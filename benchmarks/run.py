@@ -222,6 +222,10 @@ def main(argv: list[str] | None = None) -> None:
                     help="compare fresh quick metrics against committed "
                          "BENCH_*.json baselines; non-zero exit on drift")
     args = ap.parse_args(argv)
+    if not args.list:  # --list stays jax-free
+        from repro.launch.compile_cache import enable_compile_cache  # noqa: PLC0415
+
+        enable_compile_cache()
 
     if args.check_baselines:
         failures = check_baselines(args.benchmarks or None)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.core import NoiseConfig, WVConfig, WVMethod
 from repro.core.programmer import deploy_params
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ModelConfig
 from repro.models.transformer import loss_fn
 from repro.optim import AdamWConfig
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--noise", type=float, default=0.7, help="read noise, LSB")
     ap.add_argument("--n-cells", type=int, default=32)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ModelConfig(
         name="deploy-demo", n_layers=2, d_model=96, n_heads=4, n_kv_heads=2,

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.core import NoiseConfig, WVConfig, WVMethod
 from repro.core.programmer import deploy_arrays
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lifetime import (
     DriftConfig,
     LifetimeSimulator,
@@ -46,6 +47,7 @@ def main():
         choices=[p.value for p in RefreshPolicy],
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ModelConfig(
         name="lifetime-demo", n_layers=2, d_model=96, n_heads=4, n_kv_heads=2,

@@ -13,9 +13,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import WVConfig, WVMethod, program_columns
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     tkey, pkey = jax.random.split(jax.random.PRNGKey(0))
     targets = jax.random.randint(tkey, (256, 32), 0, 8).astype(jnp.float32)
 

@@ -29,6 +29,7 @@ import jax
 from repro.configs import get_smoke_config
 from repro.core import WVConfig, WVMethod
 from repro.core.programmer import deploy_arrays, deploy_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import ServeEngine
 
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--load", type=float, default=0.3,
                     help="offered load, requests per decode step")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if cfg.block == "rwkv6" or cfg.frontend == "embed_stub":

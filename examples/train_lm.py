@@ -21,6 +21,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLM
 from repro.distributed import FaultInjector, FaultTolerantRunner, StragglerMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ModelConfig
 from repro.optim import AdamWConfig
 from repro.training import init_train_state, make_train_step
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--inject-failure", type=int, nargs="*", default=())
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, seq, batch = build_cfg(args)
     data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=seq, global_batch=batch)

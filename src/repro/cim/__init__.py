@@ -5,6 +5,7 @@
 from .tile import CIMWeight, build_weight, slice_planes, tile_planes  # noqa: F401
 from .mvm import (  # noqa: F401
     CIMConfig,
+    batch_mesh,
     cim_matmul,
     cim_vmm,
     current_token_ids,

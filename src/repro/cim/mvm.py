@@ -51,9 +51,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import rng
 from repro.kernels.acim_vmm import ops as vmm_ops
@@ -68,6 +70,7 @@ __all__ = [
     "planes_per_token",
     "token_stream_ids",
     "current_token_ids",
+    "batch_mesh",
 ]
 
 
@@ -130,6 +133,91 @@ def token_stream_ids(ids: jax.Array):
 def current_token_ids() -> jax.Array | None:
     """The ambient token-id stream, or None (= flattened batch index)."""
     return _TOKEN_IDS[-1] if _TOKEN_IDS else None
+
+
+# Ambient batch mesh, entered at trace time like the token ids: the
+# serving scheduler's batch-sharded steps wrap their bodies in
+# `batch_mesh(mesh)`.  XLA cannot partition a Pallas kernel across
+# devices, so under a mesh each device runs the leaf kernel on its own
+# tokens ("data") and output channels ("model") inside `shard_map`.
+_BATCH_MESH: list = []
+
+
+@contextlib.contextmanager
+def batch_mesh(mesh):
+    """Run every `cim_matmul` in the block sharded over `mesh`: tokens
+    over "data", output channels over "model" (None: no mesh)."""
+    _BATCH_MESH.append(mesh)
+    try:
+        yield
+    finally:
+        _BATCH_MESH.pop()
+
+
+def _through_tiles(planes, ids, g_pos, g_neg, *, key, cfg, bc, full_scale,
+                   m_total=None, m_offset=0):
+    """(P, T, K) row-drive planes through every macro tile -> (P, T, M).
+
+    The read noise of tokens `ids` ((T,) int32) is drawn here, from the
+    leaf `key` (None: clean path).  A token's draw depends only on its
+    id, so drawing a device's own tokens gives the rows one device
+    would.  Each key draws all `m_total` output channels (default: M);
+    the `m_offset` window of M is kept.
+    """
+    p, t, k = planes.shape
+    n_tiles, s, _, m = g_pos.shape
+    noise = None
+    if key is not None:
+        noise = ro_noise.sample_token_read_noise(
+            key, t, s, m_total or m, cfg.sigma_read_lsb,
+            token_ids=ids, tiles=n_tiles, planes=p,
+        )  # (T_tiles, S, P*T, m_total)
+        if m_total is not None:
+            noise = jax.lax.dynamic_slice_in_dim(noise, m_offset, m, axis=3)
+    acc = vmm_ops.acim_vmm_tiled(
+        planes.reshape(p * t, k), g_pos, g_neg, bc=bc, adc_bits=cfg.adc_bits,
+        full_scale=full_scale, noise=noise, use_pallas=cfg.use_pallas,
+    )
+    return acc.reshape(p, t, m)
+
+
+def _over_mesh(tiles, mesh, planes, ids, g_pos, g_neg):
+    """`tiles` on each device's tokens and output channels.
+
+    planes (P, T, K) and `ids` split T over "data"; the tile planes
+    (Ti, S, R, M) split M over "model" where M divides, as
+    `launch.shardings.cim_weight_specs` places them.  A token's plane
+    rows and read noise, and an output channel's ADC readouts, are
+    independent of the others, so every device computes exactly what
+    one device would, with no collective.  Tokens are zero-padded to a
+    multiple of the "data" extent and the padding is sliced off.
+    Returns (P, T, M).
+    """
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    t, m = planes.shape[1], g_pos.shape[-1]
+    n_data = sizes.get("data", 1)
+    data = "data" if n_data > 1 else None
+    model = "model" if sizes.get("model", 1) > 1 and m % sizes["model"] == 0 else None
+    pad = (-t) % n_data
+    if pad:
+        planes = jnp.pad(planes, ((0, 0), (0, pad), (0, 0)))
+    if ids is None:  # clean path: no draw reads them
+        ids = jnp.zeros((t,), jnp.int32)
+    ids = jnp.pad(ids.astype(jnp.int32), (0, pad))
+    g_spec = P(None, None, None, model)
+
+    def local(x, i, gp, gn):
+        if model is None:
+            return tiles(x, i, gp, gn)
+        m_loc = gp.shape[-1]
+        return tiles(x, i, gp, gn, m_total=m,
+                     m_offset=jax.lax.axis_index("model") * m_loc)
+
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(P(None, data, None), P(data), g_spec, g_spec),
+        out_specs=P(None, data, model), check_vma=False,
+    )
+    return fn(planes, ids, g_pos, g_neg)[:, :t]
 
 
 def cim_vmm(
@@ -227,25 +315,30 @@ def cim_matmul(
     pad = n_tiles * r - k
     if pad:
         planes = jnp.pad(planes, ((0, 0), (0, 0), (0, pad)))
-    xp = planes.reshape(p * t, n_tiles * r)
-    full_scale = cfg.full_scale_frac * 2.0 * r * float(w.levels - 1)
 
-    noise = None
+    key = None
     if cfg.sigma_read_lsb > 0.0:
         key = w.key
         if w.uid is not None:
             key = rng.fold_in(key, w.uid)
         if w.layer_id is not None:
             key = rng.fold_in(key, w.layer_id)
-        noise = ro_noise.sample_token_read_noise(
-            key, t, s, m, cfg.sigma_read_lsb,
-            token_ids=token_ids, tiles=n_tiles, planes=p,
-        )  # (T_tiles, S, P*T, M)
-    acc = vmm_ops.acim_vmm_tiled(
-        xp, w.g_pos, w.g_neg, bc=w.bc, adc_bits=cfg.adc_bits,
-        full_scale=full_scale, noise=noise, use_pallas=cfg.use_pallas,
+        if token_ids is None:
+            token_ids = jnp.arange(t, dtype=jnp.int32)
+    tiles = functools.partial(
+        _through_tiles, key=key, cfg=cfg, bc=w.bc,
+        full_scale=cfg.full_scale_frac * 2.0 * r * float(w.levels - 1),
     )
+    mesh = _BATCH_MESH[-1] if _BATCH_MESH else None
+    if mesh is None:
+        acc = tiles(planes, token_ids, w.g_pos, w.g_neg)
+    else:
+        acc = _over_mesh(tiles, mesh, planes, token_ids, w.g_pos, w.g_neg)
 
-    y = jnp.einsum("pt,ptm->tm", weights, acc.reshape(p, t, m))
+    # Digital shift-and-add in f32: HIGHEST keeps the plane sums out of a
+    # bf16 MXU pass on TPU.
+    y = jnp.einsum(
+        "pt,ptm->tm", weights, acc, precision=jax.lax.Precision.HIGHEST,
+    )
     y = y * w.scale[None, :]
     return y.reshape(*lead, m).astype(x.dtype)

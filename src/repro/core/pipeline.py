@@ -141,7 +141,8 @@ def get_program_fn(
     """The shared batched-programming dispatch: (key, targets, d2d, col_ids).
 
     Returns a jitted callable ``fn(key, (C, N) targets, (C, N) d2d,
-    (C,) col_ids) -> (g, WVStats)`` cached per (cfg, cost, mesh).  The
+    (C,) col_ids) -> (g, WVStats)`` cached per (cfg, cost, mesh);
+    ``fn.lower(...)`` lowers the same jitted program.  The
     targets/d2d buffers are donated (they are bucket temporaries); when
     `mesh` is given the column axis is sharded over `mesh_axes`
     (default: all mesh axes) with zero cross-device traffic inside the
@@ -173,15 +174,29 @@ def get_program_fn(
         if donates():
             kw["donate_argnums"] = (1, 2)
         if mesh is not None:
+            # Each device programs its own column shard with its own WV
+            # loop (shard_map): columns are independent, so no collective
+            # runs inside the loop, and the Pallas kernels — which XLA
+            # cannot partition — see one device's shard.
             ax = mesh_axes if mesh_axes is not None else tuple(mesh.axis_names)
-            col2 = NamedSharding(mesh, P(ax, None))
-            col1 = NamedSharding(mesh, P(ax))
-            rep = NamedSharding(mesh, P())
-            ins = (rep, col2, col2, col1)
+            col2, col1 = P(ax, None), P(ax)
+            ins = (P(), col2, col2, col1)
             if with_fault:
                 ins = ins + (dev_mod.FaultMap(col2, col2, col2),)
-            kw["in_shardings"] = ins
-            kw["out_shardings"] = (col2, col1)  # prefix: all WVStats leaves
+            outs = (col2, col1)  # prefix: all WVStats leaves
+            # check_vma off: the body is purely per-shard (no collective),
+            # and the WV loop's zero-initialised carries are unvarying.
+            raw = jax.shard_map(
+                raw, mesh=mesh, in_specs=ins, out_specs=outs, check_vma=False
+            )
+            def named(specs):
+                return jax.tree.map(
+                    lambda s: NamedSharding(mesh, s), specs,
+                    is_leaf=lambda s: isinstance(s, P),
+                )
+
+            kw["in_shardings"] = named(ins)
+            kw["out_shardings"] = named(outs)
         jfn = jax.jit(raw, **kw)
 
         def entry(key, targets, d2d, col_ids, *fault):
@@ -195,6 +210,7 @@ def get_program_fn(
                 )
             return jfn(key, targets, d2d, col_ids, *fault)
 
+        entry.lower = jfn.lower  # the dispatched program, for inspection
         _FN_CACHE[cache_key] = entry
     return entry
 

@@ -31,6 +31,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Conductances are f32 levels carrying programming error; the MXU's
+# default f32 precision would round them to bf16 and change every
+# analog partial sum the ADC epilogue quantizes.  The reference
+# (`ref.py`) pins the same precision.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _acim_kernel(*refs, bc, adc_bits, full_scale, with_noise):
@@ -47,7 +54,8 @@ def _acim_kernel(*refs, bc, adc_bits, full_scale, with_noise):
         lo = -full_scale / 2.0
     for l in range(s):  # static unroll over bit slices
         part = jnp.dot(
-            x, gp_ref[l] - gn_ref[l], preferred_element_type=jnp.float32
+            x, gp_ref[l] - gn_ref[l],
+            precision=_PRECISION, preferred_element_type=jnp.float32,
         )
         if nz_ref is not None:
             part = part + nz_ref[l]
@@ -63,46 +71,47 @@ def _acim_kernel(*refs, bc, adc_bits, full_scale, with_noise):
 
 
 def _acim_tiled_kernel(*refs, bc, adc_bits, full_scale, with_noise):
-    """Fused whole-leaf kernel: every macro tile's slice loop + ADC
-    epilogue + tile summation in one VMEM-resident accumulation.
+    """One (B block, M block, tile) grid step of the whole-leaf kernel.
 
-    The per-tile inner accumulator recombines that tile's shifted slices
-    first and the outer accumulator adds tiles in order — the same float
-    association as the scanned reference (`ref.acim_vmm_tiled`), which
-    itself preserves the pre-fusion per-tile Python loop bit-for-bit.
+    The tile axis is the innermost, sequential grid axis: each step
+    recombines ONE tile's shifted slices into `tacc`, then adds it to
+    the output block, which stays resident in VMEM across the tile axis.
+    Tiles therefore add in order onto a zero start — the same float
+    association as the scanned reference (`ref.acim_vmm_tiled`) — while
+    VMEM holds one tile's planes at a time, whatever the leaf's depth.
     """
     if with_noise:
         x_ref, gp_ref, gn_ref, nz_ref, o_ref = refs
     else:
         x_ref, gp_ref, gn_ref, o_ref = refs
         nz_ref = None
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
     x = x_ref[...]
-    n_tiles, s, r = gp_ref.shape[0], gp_ref.shape[1], gp_ref.shape[2]
-    acc = jnp.zeros((x.shape[0], gp_ref.shape[3]), jnp.float32)
+    tacc = jnp.zeros(o_ref.shape, jnp.float32)
     if adc_bits is not None:
         w = full_scale / float(1 << adc_bits)
         lo = -full_scale / 2.0
-    for ti in range(n_tiles):  # static unroll over macro tiles
-        xi = x[:, ti * r : (ti + 1) * r]
-        tacc = jnp.zeros_like(acc)
-        for l in range(s):  # static unroll over bit slices
-            part = jnp.dot(
-                xi, gp_ref[ti, l] - gn_ref[ti, l],
-                preferred_element_type=jnp.float32,
-            )
-            if nz_ref is not None:
-                part = part + nz_ref[ti, l]
-            if adc_bits is None:
-                tacc = tacc + part * float(1 << (bc * l))
-                continue
-            code = jnp.clip(
-                jnp.round((jnp.clip(part, lo, -lo) - lo) / w),
-                0.0,
-                float((1 << adc_bits) - 1),
-            )
-            tacc = tacc + (lo + code * w) * float(1 << (bc * l))
-        acc = acc + tacc
-    o_ref[...] = acc
+    for l in range(gp_ref.shape[1]):  # static unroll over bit slices
+        part = jnp.dot(
+            x, gp_ref[0, l] - gn_ref[0, l],
+            precision=_PRECISION, preferred_element_type=jnp.float32,
+        )
+        if nz_ref is not None:
+            part = part + nz_ref[0, l]
+        if adc_bits is None:
+            tacc = tacc + part * float(1 << (bc * l))
+            continue
+        code = jnp.clip(
+            jnp.round((jnp.clip(part, lo, -lo) - lo) / w),
+            0.0,
+            float((1 << adc_bits) - 1),
+        )
+        tacc = tacc + (lo + code * w) * float(1 << (bc * l))
+    o_ref[...] = o_ref[...] + tacc
 
 
 @functools.partial(
@@ -123,9 +132,9 @@ def acim_vmm_tiled_pallas(
     interpret: bool = True,
 ) -> jax.Array:
     """One `pallas_call` for a whole weight leaf: grid over (B, M)
-    blocks, tiles and slices statically unrolled in VMEM.  The K axis
-    stays whole per block (RRAM macro rows are short), so each grid cell
-    reads its x rows once and drives every tile's conductance planes."""
+    blocks and, innermost, the macro tiles.  Each step reads tile t's
+    (block_b, R) slice of x and its (S, R, block_m) planes, so VMEM use
+    does not grow with the number of tiles."""
     b, k = x.shape
     n_tiles, s, r, m = g_pos.shape
     assert k == n_tiles * r and g_neg.shape == g_pos.shape
@@ -148,9 +157,9 @@ def acim_vmm_tiled_pallas(
     bb, mm = x.shape[0], g_pos.shape[3]
 
     in_specs = [
-        pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
-        pl.BlockSpec((n_tiles, s, r, block_m), lambda i, j: (0, 0, 0, j)),
-        pl.BlockSpec((n_tiles, s, r, block_m), lambda i, j: (0, 0, 0, j)),
+        pl.BlockSpec((block_b, r), lambda i, j, t: (i, t)),
+        pl.BlockSpec((1, s, r, block_m), lambda i, j, t: (t, 0, 0, j)),
+        pl.BlockSpec((1, s, r, block_m), lambda i, j, t: (t, 0, 0, j)),
     ]
     operands = [
         x.astype(jnp.float32),
@@ -159,7 +168,7 @@ def acim_vmm_tiled_pallas(
     ]
     if noise is not None:
         in_specs.append(
-            pl.BlockSpec((n_tiles, s, block_b, block_m), lambda i, j: (0, 0, i, j))
+            pl.BlockSpec((1, s, block_b, block_m), lambda i, j, t: (t, 0, i, j))
         )
         operands.append(noise.astype(jnp.float32))
 
@@ -168,11 +177,15 @@ def acim_vmm_tiled_pallas(
             _acim_tiled_kernel, bc=bc, adc_bits=adc_bits,
             full_scale=full_scale, with_noise=noise is not None,
         ),
-        grid=(bb // block_b, mm // block_m),
+        grid=(bb // block_b, mm // block_m, n_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_b, block_m), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bb, mm), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
+        name="acim_vmm_tiled",
     )(*operands)
     return out[:b, :m]
 
@@ -237,5 +250,6 @@ def acim_vmm_pallas(
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bb, mm), jnp.float32),
         interpret=interpret,
+        name="acim_vmm",
     )(*operands)
     return out[:b, :m]

@@ -16,7 +16,8 @@ def acim_vmm(
 
     `noise` (S, B, M) is added to each slice's analog partial sums
     before conversion; `adc_bits=None` bypasses the ADC (ideal
-    converter).  The Pallas and reference paths are bit-identical.
+    converter).  The Pallas and reference paths are bit-identical in
+    interpret mode; compiled on a TPU, see `acim_vmm_tiled`.
     """
     if not use_pallas:
         return ref.acim_vmm(x, g_pos, g_neg, bc, adc_bits, full_scale, noise)
@@ -35,9 +36,12 @@ def acim_vmm_tiled(
 
     x (B, T*R) drives per-tile planes g_pos/g_neg (T, S, R, M) with
     per-tile pre-ADC `noise` (T, S, B, M); the result (B, M) is the sum
-    over tiles of each tile's ADC-quantized slice recombination.  The
-    Pallas mega-kernel and the scanned reference are bit-identical, and
-    both preserve the pre-fusion per-tile loop's float association.
+    over tiles of each tile's ADC-quantized slice recombination.  Both
+    the Pallas mega-kernel and the scanned reference preserve the
+    pre-fusion per-tile loop's float association, and in interpret mode
+    they are bit-identical.  Compiled on a TPU, Mosaic and XLA add each
+    tile's products in different orders: pre-ADC sums differ in the
+    last bits, and rarely one converts to the neighbouring ADC code.
     """
     if not use_pallas:
         return ref.acim_vmm_tiled(

@@ -44,7 +44,11 @@ def acim_vmm(
     s = g_pos.shape[0]
     acc = jnp.zeros((x.shape[0], g_pos.shape[2]), jnp.float32)
     for l in range(s):
-        part = x.astype(jnp.float32) @ (g_pos[l] - g_neg[l]).astype(jnp.float32)
+        part = jnp.matmul(
+            x.astype(jnp.float32),
+            (g_pos[l] - g_neg[l]).astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,  # f32 conductances, no bf16 pass
+        )
         if noise is not None:
             part = part + noise[l].astype(jnp.float32)
         if adc_bits is not None:

@@ -1,21 +1,21 @@
 """Pallas TPU kernel: batched Walsh-Hadamard transform over RRAM columns.
 
-Hardware co-design note (TPU adaptation of the paper's digital decode):
-the classic O(N log N) FWHT butterfly is the right dataflow for CPUs and
-for the paper's shift-and-add periphery, but on TPU the butterfly's
-pair-swap stages are *lane-crossing* operations on the 8x128 VREG tiles,
-each compiled to expensive cross-lane shuffles.  For RRAM verify columns
-N <= 128 (the paper uses N = 32 / 64), one column fits inside a single
-MXU tile, so the transform is fastest as a dense matmul against the
-constant +-1 Sylvester matrix: the MXU performs the N^2 MACs in the same
-number of passes the VPU would need for a single butterfly stage.  We
-therefore express the kernel as a block matmul `out = x @ H` with the
-column batch tiled into VMEM blocks, and reserve the butterfly for the
-pure-jnp oracle (ref.py).
+The kernel runs the same O(N log N) butterfly as the jnp oracle
+(`core.hadamard.fwht`), stage by stage in VMEM, so its output is
+bit-identical to the oracle: every output element is the same single
+f32 add or subtract of the same two operands.  A dense `x @ H` on the
+MXU would reassociate the N-term sums (and, at default precision, round
+the conductances to bf16), which moves HARP's compare decisions.
+
+A column of N <= 128 cells (the paper uses N = 32 / 64) lies along the
+lane axis of one VREG row.  Stage s pairs lane i with lane i ^ 2^s; the
+partner value comes from a lane rotation (`pltpu.roll`) by +2^s or
+-2^s, and the rotated lane index says which of the two rotations
+delivered it, so the kernel does not depend on the rotation's sign
+convention.
 
 Grid: one program per batch block of `block_c` columns.
-BlockSpecs: x block (block_c, N) in VMEM, H (N, N) broadcast to every
-program, out block (block_c, N) in VMEM.
+BlockSpecs: x block (block_c, N) in VMEM, out block (block_c, N).
 """
 
 from __future__ import annotations
@@ -25,24 +25,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.core.hadamard import _hadamard_np
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_C = 512
 
 
-def _fwht_kernel(x_ref, h_ref, o_ref):
-    # One MXU matmul per block: (block_c, N) @ (N, N).
-    o_ref[...] = jnp.dot(
-        x_ref[...], h_ref[...], preferred_element_type=jnp.float32
-    )
+def _fwht_kernel(x_ref, o_ref, *, n):
+    x = x_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    for s in range(n.bit_length() - 1):
+        h = 1 << s
+        fwd = pltpu.roll(x, h, 1)
+        bwd = pltpu.roll(x, n - h, 1)
+        partner = jnp.where(pltpu.roll(lane, h, 1) == (lane ^ h), fwd, bwd)
+        # Oracle order: low lane a, high lane b -> (a + b, a - b).
+        x = jnp.where((lane & h) == 0, x + partner, partner - x)
+    o_ref[...] = x
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def fwht_pallas(
     x: jax.Array, *, block_c: int = DEFAULT_BLOCK_C, interpret: bool = True
 ) -> jax.Array:
-    """Batched FWHT: (C, N) -> (C, N), N a power of two <= 128.
+    """Batched FWHT: (C, N) -> (C, N) f32, N a power of two <= 128.
 
     `interpret=True` runs the kernel body on CPU for validation; on a real
     TPU backend pass interpret=False.
@@ -50,24 +55,19 @@ def fwht_pallas(
     c, n = x.shape
     if n & (n - 1) or n > 128:
         raise ValueError(f"kernel supports power-of-two N <= 128, got {n}")
-    h = jnp.asarray(_hadamard_np(n), jnp.float32)
-
     block_c = min(block_c, c)
     # Pad the column batch to a multiple of the block size.
     pad = (-c) % block_c
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    grid = (x.shape[0] // block_c,)
-
+    spec = pl.BlockSpec((block_c, n), lambda i: (i, 0))
     out = pl.pallas_call(
-        _fwht_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_c, n), lambda i: (i, 0)),
-            pl.BlockSpec((n, n), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_c, n), lambda i: (i, 0)),
+        functools.partial(_fwht_kernel, n=n),
+        grid=(x.shape[0] // block_c,),
+        in_specs=[spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], n), jnp.float32),
         interpret=interpret,
-    )(x.astype(jnp.float32), h)
+        name="fwht",
+    )(x.astype(jnp.float32))
     return out[:c]

@@ -8,7 +8,7 @@ from . import ref
 from .fwht import fwht_pallas
 
 
-def fwht(x: jax.Array, *, force_pallas: bool = False) -> jax.Array:
+def fwht(x: jax.Array) -> jax.Array:
     """Batched Walsh-Hadamard transform along the last axis.
 
     Any leading batch dims are flattened to the kernel's (C, N) layout.
@@ -21,7 +21,5 @@ def fwht(x: jax.Array, *, force_pallas: bool = False) -> jax.Array:
     if n & (n - 1) or n > 128:
         return ref.fwht(x.reshape((-1, n))).reshape(lead + (n,))
     on_tpu = jax.default_backend() == "tpu"
-    y = fwht_pallas(
-        x.reshape((-1, n)), interpret=not on_tpu if not force_pallas else False
-    )
+    y = fwht_pallas(x.reshape((-1, n)), interpret=not on_tpu)
     return y.reshape(lead + (n,))

@@ -35,26 +35,28 @@ def _wv_kernel(
     agg_ref, mag_ref, g_ref, streak_ref, frozen_ref, c2c_ref, nmap_ref,
     d2d_ref, g_out, streak_out, frozen_out, np_out, dir_out, *, p: WVCellParams
 ):
+    # Masks are f32 0/1 planes, never i1 vectors: Mosaic cannot relayout
+    # a bool vector through the row reduction and broadcast below.
     agg = agg_ref[...]
     g = g_ref[...]
-    streak = streak_ref[...]
-    frozen = frozen_ref[...] != 0
+    frozen = frozen_ref[...].astype(jnp.float32)
 
     decision = jnp.where(
         agg > p.threshold, 1.0, jnp.where(agg < -p.threshold, -1.0, 0.0)
     )
-    in_thr = decision == 0.0
-    streak_new = jnp.where(in_thr, streak + 1, 0)
-    frozen_new = frozen | (
-        jnp.asarray(p.can_freeze) & (streak_new >= p.k_streak)
-    )
-    col_active = ~jnp.all(frozen, axis=-1, keepdims=True)
+    streak_new = jnp.where(decision == 0.0, streak_ref[...] + 1, 0)
+    frozen_new = frozen
+    if p.can_freeze:  # static warmup gate
+        frozen_new = jnp.maximum(
+            frozen, jnp.where(streak_new >= p.k_streak, 1.0, 0.0)
+        )
+    col_active = 1.0 - jnp.min(frozen, axis=-1, keepdims=True)
 
     if p.ternary:
         n_p = jnp.ones_like(g)
     else:
         n_p = jnp.clip(jnp.round(mag_ref[...] / p.fine_step), 1.0, p.max_pulses)
-    act = (~frozen) & (decision != 0.0) & col_active
+    act = (1.0 - frozen) * jnp.abs(decision) * col_active > 0.0
     n_p = jnp.where(act, n_p, 0.0)
     direction = jnp.where(act, -decision, 0.0)
 
@@ -71,7 +73,7 @@ def _wv_kernel(
     )
     g_out[...] = jnp.where(n_p > 0, g_new, g)
     streak_out[...] = streak_new
-    frozen_out[...] = frozen_new.astype(jnp.int8)
+    frozen_out[...] = frozen_new.astype(jnp.int32)
     np_out[...] = n_p
     dir_out[...] = direction
 
@@ -90,7 +92,7 @@ def wv_cell_update_pallas(
     def pad2(x):
         return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
 
-    args = [agg, dev_mag, g, streak, frozen.astype(jnp.int8), c2c, nmap, d2d]
+    args = [agg, dev_mag, g, streak, frozen.astype(jnp.int32), c2c, nmap, d2d]
     args = [pad2(x) for x in args]
     rows = args[0].shape[0]
     grid = (rows // block_r,)
@@ -104,11 +106,12 @@ def wv_cell_update_pallas(
         out_shape=[
             jax.ShapeDtypeStruct((rows, n), jnp.float32),
             jax.ShapeDtypeStruct((rows, n), jnp.int32),
-            jax.ShapeDtypeStruct((rows, n), jnp.int8),
+            jax.ShapeDtypeStruct((rows, n), jnp.int32),
             jax.ShapeDtypeStruct((rows, n), jnp.float32),
             jax.ShapeDtypeStruct((rows, n), jnp.float32),
         ],
         interpret=interpret,
+        name="wv_step",
     )(*args)
     g_new, streak_new, frozen_new, n_p, direction = [o[:c] for o in outs]
     return g_new, streak_new, frozen_new != 0, n_p, direction

@@ -131,6 +131,7 @@ def run_cell(arch: str, shape: str, mesh, mesh_name: str, out_dir: str):
         hlo_bytes=bytes_job,
         collective_bytes=coll["total_bytes"],
         model_flops=rf.model_flops(cfg, spec, tokens),
+        device_kind=rf.DRYRUN_DEVICE_KIND,
         collective_detail=coll,
         memory_analysis=mem,
     ).finalize()

@@ -61,6 +61,7 @@ def run_dryrun(method: str, n_columns: int, use_pallas: bool, out_dir: str):
         hlo_bytes=cost.get("bytes accessed", 0.0) * chips,
         collective_bytes=coll["total_bytes"],
         model_flops=2.0 * cells * 50,  # ~50 sweeps x O(cells) work floor
+        device_kind=rf.DRYRUN_DEVICE_KIND,
         collective_detail=coll,
         memory_analysis=mem,
     ).finalize()
@@ -93,13 +94,14 @@ def run_real(method: str, arch: str, baseline: bool = False):
     from repro.configs import get_smoke_config
     from repro.core import pipeline
     from repro.core.programmer import deploy_params
+    from repro.launch.mesh import make_mesh
     from repro.models import init_params
 
     cfg = get_smoke_config(arch)
     params = init_params(jax.random.PRNGKey(0), cfg)
     mesh = None
     if not baseline and jax.device_count() > 1:
-        mesh = jax.make_mesh((jax.device_count(),), ("cols",))
+        mesh = make_mesh((jax.device_count(),), ("cols",))
     pipeline.reset_counters()
     t0 = time.perf_counter()
     prog, report = deploy_params(

@@ -27,10 +27,11 @@ def fmt_row(r: dict) -> str:
     We report the HLO-based compute term alongside the MODEL_FLOPS-based
     term (6ND / 2ND) and classify the bottleneck with the larger of the
     two; roofline-fraction = model-compute / (dominant-term)."""
-    from repro.launch.roofline import PEAK_FLOPS
+    from repro.launch.roofline import chip_peaks
 
     ms = lambda s: f"{s * 1e3:9.3f}"
-    model_comp = r["model_flops"] / (r["chips"] * PEAK_FLOPS)
+    peak = chip_peaks(r["device_kind"]).bf16_flops
+    model_comp = r["model_flops"] / (r["chips"] * peak)
     comp = max(r["compute_s"], model_comp)
     terms = {
         "compute": comp,

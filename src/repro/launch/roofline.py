@@ -1,10 +1,11 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e targets):
+Three terms per (arch x shape x mesh), in seconds, from the published
+peaks of the target chip (`PEAKS`, keyed by `jax.Device.device_kind`):
 
-    compute    = HLO_FLOPs / (chips * 197e12 FLOP/s)      [bf16 MXU peak]
-    memory     = HLO_bytes / (chips * 819e9 B/s)          [HBM]
-    collective = collective_bytes / (chips * 50e9 B/s)    [per-link ICI]
+    compute    = HLO_FLOPs / (chips * bf16 FLOP/s)        [MXU peak]
+    memory     = HLO_bytes / (chips * HBM bytes/s)        [HBM]
+    collective = collective_bytes / ICI link bytes/s      [per-link ICI]
 
 `compiled.cost_analysis()` supplies FLOPs / bytes-accessed of the
 SPMD-partitioned per-device module (multiplied back to chip count where
@@ -27,9 +28,41 @@ import json
 import re
 from typing import Any
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    bf16_flops: float          # FLOP/s
+    hbm_bytes: float           # capacity
+    hbm_bw: float              # bytes/s
+    ici_link_bw: float         # bytes/s per ICI link
+
+
+# Keyed by `jax.Device.device_kind`.  Source: Google Cloud documentation,
+# "TPU v5e" (system architecture): 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of ICI per chip, taken here over 4 links.
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bytes=16e9, hbm_bw=819e9,
+        ici_link_bw=1600e9 / 8 / 4,
+    ),
+}
+
+# The dry-run compiles for a described v5e chip.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of `device_kind`; a chip without published peaks raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -93,6 +126,7 @@ class RooflineTerms:
     hlo_bytes: float            # whole-job HBM bytes
     collective_bytes: float     # per-device collective result bytes
     model_flops: float
+    device_kind: str            # key of `PEAKS`
     compute_s: float = 0.0
     memory_s: float = 0.0
     collective_s: float = 0.0
@@ -103,11 +137,12 @@ class RooflineTerms:
     memory_analysis: dict = dataclasses.field(default_factory=dict)
 
     def finalize(self) -> "RooflineTerms":
-        self.compute_s = self.hlo_flops / (self.chips * PEAK_FLOPS)
-        self.memory_s = self.hlo_bytes / (self.chips * HBM_BW)
+        peaks = chip_peaks(self.device_kind)
+        self.compute_s = self.hlo_flops / (self.chips * peaks.bf16_flops)
+        self.memory_s = self.hlo_bytes / (self.chips * peaks.hbm_bw)
         # collective bytes parsed from the per-device module already;
         # each device drives its own links.
-        self.collective_s = self.collective_bytes / ICI_BW
+        self.collective_s = self.collective_bytes / peaks.ici_link_bw
         terms = {
             "compute": self.compute_s,
             "memory": self.memory_s,

@@ -141,6 +141,10 @@ def decode_batch_sharding(mesh: Mesh, cache_tree: Any) -> Any:
     the committed input sharding would make the second decode call see
     a "different" layout and silently re-lower the whole step — a
     hidden post-warmup compile the trace-count contract cannot see.
+    Trailing unsharded dims are dropped from the spec for the same
+    reason: jit outputs carry `P(None, "data")`, not `P(None, "data",
+    None, None, None)`, and an admission that sees the other spelling
+    misses the dispatch cache.
     """
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache_tree)
     out = []
@@ -167,8 +171,9 @@ def decode_vec_sharding(mesh: Mesh, n_slots: int) -> NamedSharding:
 
 
 def _drop_trivial(mesh: Mesh, spec: P) -> P:
-    """Remove mesh axes of extent 1 from a spec (partitioning over them
-    is a no-op, and GSPMD strips them from jit output shardings)."""
+    """Remove mesh axes of extent 1 and trailing unsharded dims from a
+    spec (both are no-ops, and GSPMD strips them from jit output
+    shardings)."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     out = []
     for entry in spec:
@@ -180,6 +185,8 @@ def _drop_trivial(mesh: Mesh, spec: P) -> P:
             if sizes.get(a, 1) > 1
         )
         out.append(axes if len(axes) > 1 else (axes[0] if axes else None))
+    while out and out[-1] is None:
+        out.pop()
     return P(*out)
 
 

@@ -71,7 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.cim import token_stream_ids
+from repro.cim import batch_mesh as cim_batch_mesh, token_stream_ids
 from repro.models import (
     decode_step,
     init_cache,
@@ -105,6 +105,13 @@ class Request:
 
 
 ADMISSION_POLICIES = ("fifo", "spf", "edf")
+
+# Compiler options of every serving step.  XLA may run a chain of bf16
+# ops in f32 and round once ("excess precision"), and where it does so
+# depends on the fusion, hence on the sharding.  Turned off, every op
+# rounds to its dtype, so a batch-sharded step serves the tokens an
+# unsharded one does.  No effect on f32 models.
+STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 
 
 def admission_key(policy: str, req: Request):
@@ -159,6 +166,10 @@ class RequestRecord:
     deadline: float | None = None   # absolute TTFT deadline, if any
     n_chunks: int = 1               # prefill dispatches (1 = whole-bucket)
     tokens: list = dataclasses.field(default_factory=list)
+    # Host clock (time.perf_counter) when the first token reached the
+    # host; 0.0 until then.  The only wall-clock field: TTFT on the
+    # device that served the request.
+    first_token_wall: float = 0.0
 
     @property
     def n_generated(self) -> int:
@@ -365,7 +376,9 @@ class ContinuousScheduler:
         # trace, so a steady-state serve asserts them flat.
         self.trace_counts = {"admit": 0, "decode": 0, "chunk": 0}
         self._admit_jit = self._build_admit()
-        self._decode_jit = jax.jit(self._build_decode())
+        self._decode_jit = jax.jit(
+            self._build_decode(), compiler_options=STEP_COMPILER_OPTIONS
+        )
         # Chunk dispatches specialize on (start, is_final) ONLY — the
         # chunk width is fixed and true_len/slot/rid stay traced — so
         # the compile count is bounded by 2 * max_len / C regardless of
@@ -406,15 +419,16 @@ class ContinuousScheduler:
             # One jit specializes per padded bucket shape; this bump
             # fires once per specialization (trace time only).
             self.trace_counts["admit"] += 1
-            last, single = prefill(
-                params, {"tokens": tokens}, cfg, mesh,
-                max_len=max_len, true_len=true_len,
-            )
+            with cim_batch_mesh(self.batch_mesh):
+                last, single = prefill(
+                    params, {"tokens": tokens}, cfg, mesh,
+                    max_len=max_len, true_len=true_len,
+                )
             tok = self._select_token(last[0], master, rid, jnp.int32(0))
             cache = write_cache_slot(cache, single, slot)
             return tok.astype(jnp.int32), cache
 
-        return jax.jit(admit)
+        return jax.jit(admit, compiler_options=STEP_COMPILER_OPTIONS)
 
     def _build_decode(self):
         cfg, mesh = self.cfg, self.mesh
@@ -427,7 +441,7 @@ class ContinuousScheduler:
             # request's served logits are bit-identical in any slot and
             # any batch composition (DESIGN.md Sec. 17).  Digital params
             # ignore the context entirely.
-            with token_stream_ids(rids):
+            with token_stream_ids(rids), cim_batch_mesh(self.batch_mesh):
                 logits, cache = decode_step(
                     params, cache, {"tokens": cur[:, None]}, cfg, mesh
                 )
@@ -468,11 +482,12 @@ class ContinuousScheduler:
 
         def chunk(params, cache, tokens, true_len, rid, master, slot):
             self.trace_counts["chunk"] += 1  # fires at trace time only
-            last, cache = prefill_chunk(
-                params, cache, tokens, cfg, mesh, start=start, slot=slot,
-                true_len=true_len if final else None,
-                park_pos=max_len if start == 0 else None,
-            )
+            with cim_batch_mesh(self.batch_mesh):
+                last, cache = prefill_chunk(
+                    params, cache, tokens, cfg, mesh, start=start, slot=slot,
+                    true_len=true_len if final else None,
+                    park_pos=max_len if start == 0 else None,
+                )
             if final:
                 # Same sub-stream as whole-bucket admission: the first
                 # token is bit-identical chunked or not.
@@ -480,7 +495,9 @@ class ContinuousScheduler:
                 return tok.astype(jnp.int32), cache
             return cache
 
-        fn = self._chunk_jits[(start, final)] = jax.jit(chunk)
+        fn = self._chunk_jits[(start, final)] = jax.jit(
+            chunk, compiler_options=STEP_COMPILER_OPTIONS
+        )
         return fn
 
     # ------------------------------------------------------------ plumbing
@@ -540,6 +557,7 @@ class ContinuousScheduler:
         rec = self.records[req.rid]
         if not rec.tokens:
             rec.first_token_step = t_done
+            rec.first_token_wall = time.perf_counter()
             obs.digests.observe(
                 f"{self.name}.ttft_steps", rec.ttft_steps,
                 lo=0.0, hi=self._digest_hi(), n_buckets=128,
@@ -686,6 +704,25 @@ class ContinuousScheduler:
         self._dispatch_chunk(slot)
         return True
 
+    def _decode_args(self, params: Any) -> tuple:
+        """The compiled decode step's arguments for the current slots."""
+        if self._vec_sharding is not None:
+            # Host->device placements (allowed under the guard): the
+            # per-slot vectors land pre-sharded over "data" so the
+            # compiled step never reshards its batch inputs.
+            vecs = [
+                jax.device_put(v, self._vec_sharding)
+                for v in (self._cur, self._rid, self._gen)
+            ]
+        else:
+            vecs = [jnp.asarray(v) for v in (self._cur, self._rid, self._gen)]
+        return (params, self.cache, *vecs, self.key, self._occ_digest)
+
+    def lower_decode(self) -> Any:
+        """The decode step `step()` dispatches, lowered at the current
+        arguments (for inspecting the program); ticks nothing."""
+        return self._decode_jit.lower(*self._decode_args(self.engine.params))
+
     def step(self) -> None:
         """One decode step of the whole batch + slot bookkeeping.
 
@@ -697,29 +734,9 @@ class ContinuousScheduler:
         """
         t0 = time.perf_counter()
         with obs.span("serve.decode", cat="serve") as sp:
-            params = self.engine.access_params(self.n_slots)
-            if self._vec_sharding is not None:
-                # Host->device placements (allowed under the guard): the
-                # per-slot vectors land pre-sharded over "data" so the
-                # compiled step never reshards its batch inputs.
-                vecs = [
-                    jax.device_put(v, self._vec_sharding)
-                    for v in (self._cur, self._rid, self._gen)
-                ]
-            else:
-                vecs = [
-                    jnp.asarray(self._cur),
-                    jnp.asarray(self._rid),
-                    jnp.asarray(self._gen),
-                ]
+            args = self._decode_args(self.engine.access_params(self.n_slots))
             with jax.transfer_guard_device_to_host("disallow"):
-                toks, m, dig, self.cache = self._decode_jit(
-                    params,
-                    self.cache,
-                    *vecs,
-                    self.key,
-                    self._occ_digest,
-                )
+                toks, m, dig, self.cache = self._decode_jit(*args)
             # THE per-step host sync: tokens, step metrics AND the
             # cumulative occupancy digest, one fetch.
             toks, m, dig_h = jax.device_get((toks, m, dig))

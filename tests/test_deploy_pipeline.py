@@ -115,7 +115,9 @@ def test_columns_independent_of_batch_composition(fast_cfg):
 def test_mesh_sharded_dispatch_matches(small_params, fast_cfg):
     """The column axis can be sharded over a mesh; results are unchanged
     (columns are independent — no cross-device traffic in the WV loop)."""
-    mesh = jax.make_mesh((1,), ("cols",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("cols",))
     key = jax.random.PRNGKey(21)
     dep_m, _ = deploy_arrays(
         key, small_params, fast_cfg, batched=True, min_bucket=64, mesh=mesh

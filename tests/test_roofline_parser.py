@@ -3,12 +3,12 @@
 import pytest
 
 from repro.launch.roofline import (
-    HBM_BW,
-    ICI_BW,
-    PEAK_FLOPS,
     RooflineTerms,
+    chip_peaks,
     collective_bytes_from_hlo,
 )
+
+V5E = "TPU v5 lite"
 
 HLO = """
 ENTRY %main {
@@ -36,15 +36,32 @@ def test_collective_parser_counts_and_bytes():
 
 
 def test_roofline_terms_and_bottleneck():
+    pk = chip_peaks(V5E)
     t = RooflineTerms(
         arch="a", shape="s", mesh="m", chips=256,
-        hlo_flops=256 * PEAK_FLOPS,          # exactly 1 s of compute
-        hlo_bytes=256 * HBM_BW * 0.5,        # 0.5 s of HBM
-        collective_bytes=ICI_BW * 0.25,      # 0.25 s of ICI
-        model_flops=128 * PEAK_FLOPS,
+        hlo_flops=256 * pk.bf16_flops,       # exactly 1 s of compute
+        hlo_bytes=256 * pk.hbm_bw * 0.5,     # 0.5 s of HBM
+        collective_bytes=pk.ici_link_bw * 0.25,  # 0.25 s of ICI
+        model_flops=128 * pk.bf16_flops,
+        device_kind=V5E,
     ).finalize()
     assert t.compute_s == pytest.approx(1.0)
     assert t.memory_s == pytest.approx(0.5)
     assert t.collective_s == pytest.approx(0.25)
     assert t.bottleneck == "compute"
     assert t.useful_ratio == pytest.approx(0.5)
+
+
+def test_chip_peaks_table_has_no_default():
+    """v5e carries its published peaks; an unknown chip is an error."""
+    pk = chip_peaks(V5E)
+    assert (pk.bf16_flops, pk.hbm_bw, pk.hbm_bytes) == (197e12, 819e9, 16e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("TPU v9 imaginary")
+    t = RooflineTerms(
+        arch="a", shape="s", mesh="m", chips=1, hlo_flops=1.0,
+        hlo_bytes=1.0, collective_bytes=0.0, model_flops=1.0,
+        device_kind="cpu",
+    )
+    with pytest.raises(KeyError):
+        t.finalize()
