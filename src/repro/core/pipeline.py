@@ -37,6 +37,8 @@ wrappers over it.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Sequence
 
 import jax
@@ -223,6 +225,18 @@ def sample_d2d_for(key, col_ids, shape, dev_cfg):
     return dev_mod.sample_d2d(k_d2d, shape, dev_cfg)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _loop_work(iterations: jax.Array, take: int, shards: int) -> jax.Array:
+    """One bucket's WV-loop work as int32 ``[active, loop]``: `active` sums
+    the iterations of its `take` real columns; `loop` is the loop's trip
+    count (its slowest column's iterations, filler included) times the
+    columns the loop carries, per shard.  ``active / loop`` is the loop's
+    occupancy: the share of its column-trips spent on unfinished columns."""
+    it = iterations.astype(jnp.int32)
+    trips = jnp.max(it.reshape(shards, -1), axis=1)
+    return jnp.stack([jnp.sum(it[:take]), jnp.sum(trips) * (it.shape[0] // shards)])
+
+
 def program_packed_columns(
     key: jax.Array,
     blocks: Sequence[jax.Array],
@@ -239,7 +253,7 @@ def program_packed_columns(
     fault_cfg: FaultConfig | None = None,
 ) -> tuple[
     list[jax.Array], list[WVStats], list[jax.Array],
-    list[dev_mod.FaultMap] | list[None],
+    list[dev_mod.FaultMap] | list[None], jax.Array,
 ]:
     """Program many packed column blocks in a few bucketed dispatches.
 
@@ -265,60 +279,63 @@ def program_packed_columns(
         programming runs under it.  Returned per block so callers can
         persist it alongside d2d.
 
-    Returns (g_blocks, stats_blocks, d2d_blocks, fault_blocks), all
-    split back to the input block boundaries.  `fault_blocks` is a list
-    of None when no fault config is given.  Everything stays on device;
-    no host syncs.
+    Returns (g_blocks, stats_blocks, d2d_blocks, fault_blocks,
+    loop_work): the first four split back to the input block boundaries
+    (`fault_blocks` is a list of None when no fault config is given);
+    `loop_work` is a (buckets, 2) int32 array of each bucket's
+    ``[active, loop]`` column-iterations (`_loop_work`).  Everything
+    stays on device; no host syncs.  The `deploy.dispatch` span covers
+    the d2d draw and the dispatches, and ends when they are enqueued,
+    not when the device finishes them.
     """
     if cost is None:
         cost = CircuitCost()
     sizes = [int(b.shape[0]) for b in blocks]
     c_total = sum(sizes)
     if c_total == 0:
-        return [], [], [], []
+        return [], [], [], [], jnp.zeros((0, 2), jnp.int32)
     n = int(blocks[0].shape[1])
-    targets = jnp.concatenate(blocks, axis=0) if len(blocks) > 1 else blocks[0]
-    targets = targets.astype(jnp.float32)
-    if uids is None:
-        uids = uid_base + jnp.arange(c_total, dtype=jnp.int32)
-    else:
-        uids = jnp.asarray(uids, jnp.int32)
-        assert uids.shape == (c_total,), (uids.shape, c_total)
-    if pad_uid_base is None:
-        pad_uid_base = uid_base + c_total
-    # d2d is sampled OUTSIDE the donated dispatch: it is persistent array
-    # state (ArrayState.d2d) while the padded bucket buffers are
-    # temporaries.  Same sub-streams as the engine would use internally.
-    d2d = sample_d2d_for(key, uids, (c_total, n), cfg.device)
-    # The fault map is persistent silicon state like d2d: sampled here
-    # (salted key domain — write-noise streams are untouched) and passed
-    # through every dispatch, never resampled inside.
-    with_fault = fault_cfg is not None and fault_cfg.any_faults
-    fault = (
-        dev_mod.sample_fault_map(key, uids, (c_total, n), fault_cfg, cfg.device)
-        if with_fault
-        else None
-    )
-
-    fn = get_program_fn(
-        cfg, cost, mesh=mesh, mesh_axes=mesh_axes, with_fault=with_fault
-    )
     sizes_plan = bucket_sizes(c_total, min_bucket, max_bucket)
-    g_parts, stat_parts = [], []
-    off = 0
+    # A bucket's columns split into equal contiguous shards, one WV loop
+    # per device, so the loop's trip count is taken per shard.
+    shards = (
+        1 if mesh is None else
+        math.prod(mesh.shape[a] for a in (mesh_axes or mesh.axis_names))
+    )
     with obs.span(
-        "deploy.program_columns", cat="pipeline",
+        "deploy.dispatch", cat="pipeline",
         columns=c_total, buckets=len(sizes_plan), blocks=len(blocks),
     ):
+        targets = jnp.concatenate(blocks, axis=0) if len(blocks) > 1 else blocks[0]
+        targets = targets.astype(jnp.float32)
+        if uids is None:
+            uids = uid_base + jnp.arange(c_total, dtype=jnp.int32)
+        else:
+            uids = jnp.asarray(uids, jnp.int32)
+            assert uids.shape == (c_total,), (uids.shape, c_total)
+        if pad_uid_base is None:
+            pad_uid_base = uid_base + c_total
+        # d2d is sampled OUTSIDE the donated dispatch: it is persistent array
+        # state (ArrayState.d2d) while the padded bucket buffers are
+        # temporaries.  Same sub-streams as the engine would use internally.
+        d2d = sample_d2d_for(key, uids, (c_total, n), cfg.device)
+        # The fault map is persistent silicon state like d2d: sampled here
+        # (salted key domain — write-noise streams are untouched) and passed
+        # through every dispatch, never resampled inside.
+        with_fault = fault_cfg is not None and fault_cfg.any_faults
+        fault = (
+            dev_mod.sample_fault_map(key, uids, (c_total, n), fault_cfg, cfg.device)
+            if with_fault
+            else None
+        )
+
+        fn = get_program_fn(
+            cfg, cost, mesh=mesh, mesh_axes=mesh_axes, with_fault=with_fault
+        )
+        g_parts, stat_parts, loop_parts = [], [], []
+        off = 0
         for size in sizes_plan:
             take = min(size, c_total - off)
-            # Host-side shape bookkeeping (ints already on host): the
-            # dispatch-size digest lets the dashboard show how well the
-            # bucket menu fits real models — zero device work.
-            obs.digests.observe(
-                "pipeline.bucket_columns", float(take),
-                lo=0.0, hi=float(DEFAULT_MAX_BUCKET), n_buckets=64,
-            )
             tb = targets[off : off + take]
             db = d2d[off : off + take]
             ub = uids[off : off + take]
@@ -354,23 +371,25 @@ def program_packed_columns(
             g_b, st_b = fn(key, tb, db, ub, *fargs)
             g_parts.append(g_b[:take])
             stat_parts.append(jax.tree.map(lambda x: x[:take], st_b))
+            loop_parts.append(_loop_work(st_b.iterations, take, shards))
             off += take
 
-    g_all = jnp.concatenate(g_parts) if len(g_parts) > 1 else g_parts[0]
-    stats_all = (
-        jax.tree.map(lambda *xs: jnp.concatenate(xs), *stat_parts)
-        if len(stat_parts) > 1
-        else stat_parts[0]
-    )
-    g_blocks, stats_blocks, d2d_blocks, fault_blocks = [], [], [], []
-    off = 0
-    for c_i in sizes:
-        g_blocks.append(g_all[off : off + c_i])
-        stats_blocks.append(jax.tree.map(lambda x: x[off : off + c_i], stats_all))
-        d2d_blocks.append(d2d[off : off + c_i])
-        fault_blocks.append(
-            jax.tree.map(lambda x: x[off : off + c_i], fault)
-            if with_fault else None
+        loop_work = jnp.stack(loop_parts)
+        g_all = jnp.concatenate(g_parts) if len(g_parts) > 1 else g_parts[0]
+        stats_all = (
+            jax.tree.map(lambda *xs: jnp.concatenate(xs), *stat_parts)
+            if len(stat_parts) > 1
+            else stat_parts[0]
         )
-        off += c_i
-    return g_blocks, stats_blocks, d2d_blocks, fault_blocks
+        g_blocks, stats_blocks, d2d_blocks, fault_blocks = [], [], [], []
+        off = 0
+        for c_i in sizes:
+            g_blocks.append(g_all[off : off + c_i])
+            stats_blocks.append(jax.tree.map(lambda x: x[off : off + c_i], stats_all))
+            d2d_blocks.append(d2d[off : off + c_i])
+            fault_blocks.append(
+                jax.tree.map(lambda x: x[off : off + c_i], fault)
+                if with_fault else None
+            )
+            off += c_i
+    return g_blocks, stats_blocks, d2d_blocks, fault_blocks, loop_work

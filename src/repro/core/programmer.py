@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -103,31 +103,34 @@ class DeployReport:
     total_gave_up_cells: float = 0.0  # cells declared unprogrammable
     total_retry_pulses: float = 0.0   # pulses burned on gave-up cells
     remapped_columns: int = 0         # primaries repaired onto spares
+    # The WV loops' occupancy (`pipeline._loop_work`): iterations of the
+    # columns while still being programmed, against trips x columns of
+    # every loop that carried them (bucket filler included).
+    active_column_iterations: int = 0
+    loop_column_iterations: int = 0
     leaves: dict[str, dict[str, float]] = dataclasses.field(default_factory=dict)
-    # Fetched `extra` tree from collect() (per-tile health reductions,
-    # deploy digests).  Deliberately NOT a dataclass field: it is a
-    # transport slot for the fold in deploy_arrays, not part of the
-    # report's stable scalar surface.
-    extra = None
 
-    @classmethod
-    def collect(
-        cls,
+    @staticmethod
+    def reductions(
         leaf_stats: "dict[str, WVStats]",
-        n_cells: int,
         remapped: "dict[str, jax.Array] | None" = None,
+        loop_works: "Sequence[jax.Array]" = (),
         extra: Any | None = None,
-    ) -> "DeployReport":
-        """Device-side report accumulation with exactly ONE host sync.
+    ) -> tuple:
+        """The report's device-side reductions, for ONE host sync.
 
         All reductions (per-leaf and aggregate) are jnp ops over the
-        still-on-device `WVStats` arrays; a single `pipeline.host_fetch`
-        (device_get) at the end transfers the handful of scalars.  This
-        is the batched-deployment stats contract (DESIGN.md Sec. 10):
-        nothing in the deploy loop blocks on the device.
+        still-on-device `WVStats` arrays; building them never blocks.
+        The caller transfers the returned tree with a single
+        `pipeline.host_fetch` (device_get) and builds the report with
+        `from_fetched`.  This is the batched-deployment stats contract
+        (DESIGN.md Sec. 10): nothing in the deploy loop blocks on the
+        device.  `loop_works` are the programming passes' per-bucket
+        loop work (`pipeline.program_packed_columns`); `extra` is an
+        arbitrary device tree (per-tile health reductions, deploy
+        digests — DESIGN.md Sec. 16) riding the SAME fetch, returned last
+        so the caller can fold its host copy.
         """
-        if not leaf_stats:
-            return cls()
         stats = list(leaf_stats.values())
         its = jnp.concatenate([s.iterations for s in stats])
         lat = jnp.concatenate([s.latency_ns for s in stats])
@@ -165,16 +168,26 @@ class DeployReport:
             )
             for name, s in leaf_stats.items()
         }
-        # `extra` is an arbitrary device tree (per-tile health reductions,
-        # deploy digests — DESIGN.md Sec. 16) riding the SAME single
-        # fetch; the caller folds the fetched host copy afterwards.
-        agg_h, per_h, rem_h, extra_h = pipeline.host_fetch(
-            (agg, per, remapped or {}, extra)
+        return agg, per, remapped or {}, list(loop_works), extra
+
+    @classmethod
+    def from_fetched(
+        cls, leaf_stats: "dict[str, WVStats]", n_cells: int, fetched: tuple
+    ) -> "DeployReport":
+        """The report from the host copy of `reductions`' tree."""
+        agg_h, per_h, rem_h, loops_h, _ = fetched
+        # Per-bucket int32 values, summed on the host without overflow.
+        active, loop = sum(
+            (np.asarray(w, np.int64).sum(axis=0) for w in loops_h),
+            np.zeros(2, np.int64),
         )
+        stats = list(leaf_stats.values())
         report = cls(
             num_columns=sum(int(s.iterations.shape[0]) for s in stats),
             num_cells=sum(int(s.iterations.shape[0]) * n_cells for s in stats),
             remapped_columns=int(sum(float(v) for v in rem_h.values())),
+            active_column_iterations=int(active),
+            loop_column_iterations=int(loop),
             **{k: float(v) for k, v in agg_h.items()},
         )
         report.leaves = {
@@ -186,7 +199,6 @@ class DeployReport:
         }
         for name, v in rem_h.items():
             report.leaves[name]["remapped_columns"] = float(v)
-        report.extra = extra_h
         return report
 
     def merge(self, name: str, stats: WVStats, n_cells: int) -> None:
@@ -196,6 +208,10 @@ class DeployReport:
         en = float(jnp.sum(stats.energy_pj))
         it = float(jnp.mean(stats.iterations))
         rms = float(jnp.sqrt(jnp.mean(stats.rms_error_lsb**2)))
+        # One leaf is one loop with no filler: its trips are its slowest
+        # column's iterations.
+        self.active_column_iterations += int(jnp.sum(stats.iterations))
+        self.loop_column_iterations += c * int(jnp.max(stats.iterations))
         self.total_reads += float(jnp.sum(stats.reads))
         self.total_write_pulses += float(jnp.sum(stats.write_pulses))
         self.total_gave_up_cells += float(jnp.sum(stats.gave_up))
@@ -355,7 +371,7 @@ def _deploy_health_tree(
     """Device tree of per-tile health reductions + deploy digests.
 
     Everything here is a jnp reduction (or host uid bookkeeping) meant
-    to ride the deploy's single `host_fetch` via `DeployReport.collect
+    to ride the deploy's single `host_fetch` via `DeployReport.reductions
     (extra=...)` — building it never synchronizes (DESIGN.md Sec. 16).
     """
     cpt = (fault_cfg or FaultConfig()).columns_per_tile
@@ -382,6 +398,52 @@ def _fold_deploy_health(extra_h: dict[str, Any] | None) -> None:
         obs.health_registry.fold_tiles(f"deploy.{metric}", tile_ids, vals)
     for name, dig in extra_h["digests"].items():
         obs.digests.fold(name, dig)
+
+
+def _fold_deploy(report: "DeployReport", wv_cfg: WVConfig, cost: CircuitCost) -> None:
+    """Registry counters and ledger charges of one deploy (DESIGN.md
+    Sec. 14): every value was already fetched by the report's host
+    sync(s) — pure host floats."""
+    obs.registry.fold(
+        {
+            "columns": report.num_columns,
+            "verify_reads": report.total_reads,
+            "write_pulses": report.total_write_pulses,
+            # Contract-bearing give-up/remap counters (DESIGN.md Sec. 15).
+            "gave_up_cells": report.total_gave_up_cells,
+            "retry_pulses": report.total_retry_pulses,
+            "remapped_columns": report.remapped_columns,
+            # The WV loops' occupancy: active over loop column-iterations.
+            "active_column_iterations": report.active_column_iterations,
+            "loop_column_iterations": report.loop_column_iterations,
+        },
+        prefix="deploy.",
+    )
+    obs.charge(
+        "deploy",
+        energy_pj=report.total_energy_pj,
+        latency_ns=report.critical_latency_ns,
+        reads=report.total_reads,
+        method=wv_cfg.method.value,
+        columns=report.num_columns,
+    )
+    if report.total_gave_up_cells or report.remapped_columns:
+        # Ledger attribution of the bounded-retry waste: energy of the
+        # pulses burned on cells that were ultimately given up on,
+        # estimated at mid-scale conductance (the per-pulse energy model
+        # of cost.write_phase_cost, g = G_max/2).
+        e_pulse_pj = (
+            cost.v_set**2
+            * (wv_cfg.device.g_max_lsb / 2.0 * cost.g_lsb_us)
+            * cost.t_write_pulse_ns * 1e-3
+        )
+        obs.charge(
+            "deploy.give_up",
+            energy_pj=report.total_retry_pulses * e_pulse_pj,
+            gave_up_cells=report.total_gave_up_cells,
+            retry_pulses=report.total_retry_pulses,
+            remapped_columns=report.remapped_columns,
+        )
 
 
 # Integer-exact, so compiling it whole changes no value.  Run op by op,
@@ -517,6 +579,14 @@ def deploy_arrays(
     `sensitivity(name, leaf)` onto the cleanest probed tiles.  All remap
     decisions are device-side; the deploy still performs exactly one
     host sync, with give-up/remap accounting riding it.
+
+    Spans (DESIGN.md Sec. 14): `deploy` covers the whole call, and its
+    args carry `columns`, `active_column_iterations` and
+    `loop_column_iterations`.  Its children are `deploy.plan` (quantize
+    and pack), `deploy.dispatch` (the d2d draw and the bucket
+    dispatches), `deploy.report` (the health tree and the report's
+    reductions), `deploy.sync` (the host fetch's wait for the device)
+    and `deploy.fold` (everything after the sync).
     """
     if q_cfg is None:
         q_cfg = QuantConfig(
@@ -530,28 +600,29 @@ def deploy_arrays(
         raise ValueError(
             "fault_cfg/remap_cfg require the batched deployment path"
         )
-    records, treedef = _eligible_leaves(params, deploy_embeddings, predicate)
-    leaves: list = []
-    slots: dict[str, int] = {}
-    plans: list[_LeafPlan] = []
-    uid = 0
-    for i, name, leaf, eligible in records:
-        if not eligible:
-            leaves.append(leaf)
-            continue
-        plan = _plan_leaf(name, leaf, wv_cfg, q_cfg, uid)
-        uid += int(plan.cols.shape[0])
-        slots[name] = len(leaves)
-        plans.append(plan)
-        leaves.append(None)
-
-    arrays: dict[str, ArrayState] = {}
     with obs.span(
-        "deploy", cat="deploy", method=wv_cfg.method.value,
-        leaves=len(plans), batched=batched,
+        "deploy", cat="deploy", method=wv_cfg.method.value, batched=batched,
     ) as sp:
+        records, treedef = _eligible_leaves(params, deploy_embeddings, predicate)
+        leaves: list = []
+        slots: dict[str, int] = {}
+        plans: list[_LeafPlan] = []
+        with obs.span("deploy.plan", cat="deploy"):
+            uid = 0
+            for i, name, leaf, eligible in records:
+                if not eligible:
+                    leaves.append(leaf)
+                    continue
+                plan = _plan_leaf(name, leaf, wv_cfg, q_cfg, uid)
+                uid += int(plan.cols.shape[0])
+                slots[name] = len(leaves)
+                plans.append(plan)
+                leaves.append(None)
+        sp["leaves"] = len(plans)
+
+        arrays: dict[str, ArrayState] = {}
         if batched and not use_remap:
-            g_blocks, stats_blocks, d2d_blocks, fault_blocks = (
+            g_blocks, stats_blocks, d2d_blocks, fault_blocks, loop_work = (
                 pipeline.program_packed_columns(
                     key, [p.cols for p in plans], wv_cfg, cost,
                     mesh=mesh, min_bucket=min_bucket, max_bucket=max_bucket,
@@ -563,18 +634,15 @@ def deploy_arrays(
             ):
                 arrays[plan.name] = plan.state(g, d2d, fault=fb)
             stats_map = {p.name: s for p, s in zip(plans, stats_blocks)}
-            uids_map = {p.name: arrays[p.name].uids for p in plans}
-            report = DeployReport.collect(
-                stats_map, wv_cfg.n_cells,
-                extra=_deploy_health_tree(stats_map, uids_map, fault_cfg),
-            )
+            remapped = extra_columns = None
+            loop_works = [loop_work]
         elif batched:
             # Two-pass spare-column deploy (DESIGN.md Sec. 15).  Pass A
             # programs every leaf's primary columns; the worst columns
             # (by give-up count) pick spare candidates DEVICE-SIDE; pass
             # B programs the spares at their own physical uids; the
             # remap table is decided device-side from both passes'
-            # stats.  One host sync total, in the report collect below.
+            # stats.  One host sync total, in `deploy.sync` below.
             c_counts = [int(p.cols.shape[0]) for p in plans]
             s_counts = [remap_mod.n_spares(c, remap_cfg) for c in c_counts]
             phys_counts = [c + s for c, s in zip(c_counts, s_counts)]
@@ -604,7 +672,7 @@ def deploy_arrays(
                 [ua[c:] for ua, c in zip(uid_arrays, c_counts)]
             )
             fc = fault_cfg if use_fault else None
-            g_blocks, stats_blocks, d2d_blocks, fault_blocks = (
+            g_blocks, stats_blocks, d2d_blocks, fault_blocks, loop_a = (
                 pipeline.program_packed_columns(
                     key, [p.cols for p in plans], wv_cfg, cost,
                     mesh=mesh, min_bucket=min_bucket, max_bucket=max_bucket,
@@ -615,7 +683,7 @@ def deploy_arrays(
                 remap_mod.spare_candidates(st.gave_up, s)
                 for st, s in zip(stats_blocks, s_counts)
             ]
-            sg_blocks, sstats_blocks, sd2d_blocks, sfault_blocks = (
+            sg_blocks, sstats_blocks, sd2d_blocks, sfault_blocks, loop_b = (
                 pipeline.program_packed_columns(
                     key,
                     [p.cols[cand] for p, cand in zip(plans, cands)],
@@ -625,7 +693,7 @@ def deploy_arrays(
                 )
             )
             remapped: dict[str, jax.Array] = {}
-            combined: dict[str, WVStats] = {}
+            stats_map: dict[str, WVStats] = {}
             remap_flags: dict[str, jax.Array] = {}
             cat = lambda a, b: jnp.concatenate([a, b])  # noqa: E731
             for plan, ua, c, cand, g, st, d2d, fb, sg, sst, sd2d, sfb in zip(
@@ -646,7 +714,7 @@ def deploy_arrays(
                     remap=table,
                     uids=ua,
                 )
-                combined[plan.name] = jax.tree.map(cat, st, sst)
+                stats_map[plan.name] = jax.tree.map(cat, st, sst)
                 not_active = (~table.active[:c]).astype(jnp.float32)
                 remapped[plan.name] = jnp.sum(not_active)
                 # Per-column remap flags (physical order: primaries then
@@ -654,65 +722,43 @@ def deploy_arrays(
                 remap_flags[plan.name] = jnp.concatenate(
                     [not_active, jnp.zeros((len(ua) - c,), jnp.float32)]
                 )
-            uids_map = {p.name: arrays[p.name].uids for p in plans}
-            report = DeployReport.collect(
-                combined, wv_cfg.n_cells, remapped=remapped,
-                extra=_deploy_health_tree(
-                    combined, uids_map, fault_cfg,
-                    extra_columns={"remapped_columns": remap_flags},
-                ),
-            )
+            extra_columns = {"remapped_columns": remap_flags}
+            loop_works = [loop_a, loop_b]
+        if batched:
+            with obs.span("deploy.report", cat="deploy"):
+                uids_map = {p.name: arrays[p.name].uids for p in plans}
+                pending = DeployReport.reductions(
+                    stats_map, remapped=remapped, loop_works=loop_works,
+                    extra=_deploy_health_tree(
+                        stats_map, uids_map, fault_cfg,
+                        extra_columns=extra_columns,
+                    ),
+                )
+            with obs.span("deploy.sync", cat="deploy"):
+                fetched = pipeline.host_fetch(pending)
+            with obs.span("deploy.fold", cat="deploy"):
+                report = DeployReport.from_fetched(
+                    stats_map, wv_cfg.n_cells, fetched
+                )
+                # Health/digest fold (DESIGN.md Sec. 16): the per-tile
+                # reductions and deploy digests were fetched BY the
+                # report's single host sync; folding them is pure host
+                # work.
+                _fold_deploy_health(fetched[-1])
+                _fold_deploy(report, wv_cfg, cost)
         else:
             report = DeployReport()
             for plan in plans:
                 state, stats = _program_plan(key, plan, wv_cfg, cost)
-                report.merge(plan.name, stats, wv_cfg.n_cells)
+                with obs.span("deploy.sync", cat="deploy"):
+                    report.merge(plan.name, stats, wv_cfg.n_cells)
                 arrays[plan.name] = state
+            with obs.span("deploy.fold", cat="deploy"):
+                _fold_deploy(report, wv_cfg, cost)
         sp["columns"] = report.num_columns
         sp["rms_cell_error_lsb"] = report.rms_cell_error_lsb
-    # Health/digest fold (DESIGN.md Sec. 16): the per-tile reductions
-    # and deploy digests were fetched BY the report's single host sync;
-    # folding them here is pure host work.
-    _fold_deploy_health(report.extra)
-    # Telemetry attribution (DESIGN.md Sec. 14): all values above were
-    # already fetched by the report's host sync(s) — pure host floats.
-    obs.registry.fold(
-        {
-            "columns": report.num_columns,
-            "verify_reads": report.total_reads,
-            "write_pulses": report.total_write_pulses,
-            # Contract-bearing give-up/remap counters (DESIGN.md Sec. 15).
-            "gave_up_cells": report.total_gave_up_cells,
-            "retry_pulses": report.total_retry_pulses,
-            "remapped_columns": report.remapped_columns,
-        },
-        prefix="deploy.",
-    )
-    obs.charge(
-        "deploy",
-        energy_pj=report.total_energy_pj,
-        latency_ns=report.critical_latency_ns,
-        reads=report.total_reads,
-        method=wv_cfg.method.value,
-        columns=report.num_columns,
-    )
-    if report.total_gave_up_cells or report.remapped_columns:
-        # Ledger attribution of the bounded-retry waste: energy of the
-        # pulses burned on cells that were ultimately given up on,
-        # estimated at mid-scale conductance (the per-pulse energy model
-        # of cost.write_phase_cost, g = G_max/2).
-        e_pulse_pj = (
-            cost.v_set**2
-            * (wv_cfg.device.g_max_lsb / 2.0 * cost.g_lsb_us)
-            * cost.t_write_pulse_ns * 1e-3
-        )
-        obs.charge(
-            "deploy.give_up",
-            energy_pj=report.total_retry_pulses * e_pulse_pj,
-            gave_up_cells=report.total_gave_up_cells,
-            retry_pulses=report.total_retry_pulses,
-            remapped_columns=report.remapped_columns,
-        )
+        sp["active_column_iterations"] = report.active_column_iterations
+        sp["loop_column_iterations"] = report.loop_column_iterations
     return (
         DeployedModel(
             treedef=treedef, leaves=leaves, slots=slots, arrays=arrays,

@@ -8,7 +8,7 @@ still meet its objectives" from signals the stack already produces
   per-column WV statistics into per-tile sums with jnp segment sums.
   The tile axis is tiny (columns / columns_per_tile), so the per-tile
   arrays ride the host syncs the paths already perform: the deploy's
-  single `host_fetch` (`DeployReport.collect`) and the scrub's drift
+  single `host_fetch` (`DeployReport.reductions`) and the scrub's drift
   fetch.  Column->tile assignment comes from the deploy's physical
   column uids (host numpy), so no device work is needed to route it.
 * **Host-side registry** — `HealthRegistry` folds the fetched per-tile

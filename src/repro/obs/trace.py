@@ -9,6 +9,13 @@ here touches the device, so tracing can never add a host sync or a
 retrace to an instrumented hot path (the zero-extra-sync contract,
 DESIGN.md Sec. 14).
 
+Each recorded span is also a `jax.profiler.TraceAnnotation` of the same
+name: inside a profiler capture (`jax.profiler.start_trace` /
+`stop_trace`) the program's spans land on the host plane of the
+`.xplane.pb`, on the same clock as the device's operations, so device
+idle time can be charged to what the host was doing.  Outside a capture
+an annotation records nothing.
+
 Usage:
 
     from repro.obs import trace
@@ -23,9 +30,9 @@ microseconds; `instant` emits "ph": "i" markers (compiles, swaps);
 ledger charges ride along as "cat": "ledger" instants (`obs.ledger`).
 `repro.obs.report` summarizes an exported file per phase name.
 
-Recording honours the global obs enable flag (`obs.disabled()`); the
-`span` context manager itself keeps timing (benchmarks' `timed()` is
-built on it) even when event recording is off.
+Recording honours the global obs enable flag (`obs.disabled()`), read
+when a span opens: a span opened while it is off records no event and
+opens no annotation.
 """
 
 from __future__ import annotations
@@ -35,6 +42,8 @@ import json
 import os
 import time
 from typing import Any, Iterator
+
+import jax
 
 __all__ = [
     "Tracer",
@@ -86,14 +95,20 @@ class Tracer:
         """Record one complete ("ph": "X") event around the body.
 
         Yields the (mutable) args dict so the body can attach results —
-        values filled in before exit land in the exported event.
+        values filled in before exit land in the exported event.  While
+        recording, the body also runs inside a `TraceAnnotation` of the
+        same name, opened and closed next to the span's own clock reads.
         """
-        ts = self.now_us()
         mutable = dict(args)
-        try:
+        if not _ENABLED:
             yield mutable
+            return
+        ts = self.now_us()
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield mutable
         finally:
-            self._append(
+            self._events.append(
                 {
                     "name": name,
                     "cat": cat,
@@ -118,20 +133,6 @@ class Tracer:
                 "pid": self.pid,
                 "tid": 1,
                 "args": dict(args),
-            }
-        )
-
-    def counter(self, name: str, cat: str = "metric", **values: float) -> None:
-        """Record a counter sample ("ph": "C") — renders as a track."""
-        self._append(
-            {
-                "name": name,
-                "cat": cat,
-                "ph": "C",
-                "ts": self.now_us(),
-                "pid": self.pid,
-                "tid": 1,
-                "args": {k: float(v) for k, v in values.items()},
             }
         )
 
@@ -168,10 +169,6 @@ def span(name: str, cat: str = "phase", **args: Any):
 
 def instant(name: str, cat: str = "phase", **args: Any) -> None:
     tracer.instant(name, cat=cat, **args)
-
-
-def counter(name: str, cat: str = "metric", **values: float) -> None:
-    tracer.counter(name, cat=cat, **values)
 
 
 def events() -> list[dict]:

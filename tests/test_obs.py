@@ -23,9 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro import obs
-from repro.core import WVConfig, WVMethod, pipeline
+from repro.core import CircuitCost, WVConfig, WVMethod, pipeline
 from repro.core.programmer import deploy_arrays
 from repro.models import ModelConfig, init_params
 from repro.obs import ledger, metrics, report, trace
@@ -95,16 +96,15 @@ def test_span_instant_counter_events_and_disabled():
     with trace.span("phase.a", cat="t", n=1) as sp:
         sp["result"] = 42
     trace.instant("marker", cat="t")
-    trace.counter("load", slots=3)
     evs = trace.events()
-    assert [e["ph"] for e in evs] == ["X", "i", "C"]
+    assert [e["ph"] for e in evs] == ["X", "i"]
     assert evs[0]["args"] == {"n": 1, "result": 42}
     assert evs[0]["dur"] >= 0
     with obs.disabled():
         with trace.span("phase.hidden"):
             pass
         ledger.charge("hidden", energy_pj=1.0)
-    assert len(trace.events()) == 3  # nothing recorded while disabled
+    assert len(trace.events()) == 2  # nothing recorded while disabled
     assert ledger.summary() == {}
 
 
@@ -214,7 +214,89 @@ def test_deploy_bit_neutral_and_single_sync():
     assert metrics.value("deploy.write_pulses") > 0
     assert ledger.summary()["deploy"]["energy_pj"] > 0
     spans = [e["name"] for e in trace.events() if e["ph"] == "X"]
-    assert "deploy" in spans and "deploy.program_columns" in spans
+    assert "deploy" in spans and "deploy.dispatch" in spans
+
+
+DEPLOY_SPANS = ("deploy", "deploy.plan", "deploy.dispatch", "deploy.report",
+                "deploy.sync", "deploy.fold")
+
+
+def test_deploy_spans_on_the_profiler_timeline(tmp_path):
+    """Inside a profiler capture every deploy span is also a host-plane
+    event, nested inside `deploy`, lasting what `obs.trace` recorded."""
+    params = _tiny_params()
+    wv = WVConfig(method=WVMethod.HARP, max_fine_iters=8, max_coarse_iters=3)
+    deploy_arrays(jax.random.PRNGKey(1), params, wv)  # compile outside
+    trace.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        deploy_arrays(jax.random.PRNGKey(2), params, wv)
+    finally:
+        jax.profiler.stop_trace()
+    recorded = {e["name"]: e for e in trace.events() if e["ph"] == "X"}
+    assert set(recorded) == set(DEPLOY_SPANS)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host: dict = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in DEPLOY_SPANS:
+                        host.setdefault(e.name, []).append(e)
+    assert {k: len(v) for k, v in host.items()} == dict.fromkeys(DEPLOY_SPANS, 1)
+    deploy = host["deploy"][0]
+    for name in DEPLOY_SPANS:
+        (e,) = host[name]
+        assert deploy.start_ns <= e.start_ns and e.end_ns <= deploy.end_ns, name
+        assert abs(e.duration_ns / 1e3 - recorded[name]["dur"]) < 1e3, name
+
+
+@pytest.mark.parametrize("method", [WVMethod.HARP, WVMethod.MRA])
+def test_deploy_loop_occupancy_counters(method):
+    """The loop counters ride the deploy's one host sync: `loop` is each
+    bucket's trips (its slowest column, filler included) times its
+    columns, `active` the real columns' iterations, both equal to the
+    buckets' loops run again through the shared dispatch.  (HARP's
+    loops run to the 50-trip cap here, MRA's end before it.)"""
+    params = _tiny_params()
+    wv = WVConfig(method=method, max_coarse_iters=3)
+    kw = dict(min_bucket=256, max_bucket=256)  # 448 columns: 64 filler
+    key = jax.random.PRNGKey(2)
+    deploy_arrays(jax.random.PRNGKey(3), params, wv, **kw)  # warm
+    pipeline.reset_counters()
+    metrics.reset("deploy.")
+    deployed, rep = deploy_arrays(key, params, wv, **kw)
+    assert pipeline.host_sync_count() == 1
+    assert pipeline.compile_count() == 0
+
+    states = sorted(deployed.arrays.values(), key=lambda a: int(a.uids[0]))
+    targets = jnp.concatenate([a.targets for a in states])
+    uids = jnp.asarray(np.concatenate([a.uids for a in states]), jnp.int32)
+    c = int(uids.shape[0])
+    d2d = pipeline.sample_d2d_for(key, uids, targets.shape, wv.device)
+    fn = pipeline.get_program_fn(wv, CircuitCost())
+    active = loop = off = 0
+    for size in pipeline.bucket_sizes(c, **kw):
+        take = min(size, c - off)
+        pad = size - take
+        _, st = fn(
+            key,
+            jnp.pad(targets[off : off + take], ((0, pad), (0, 0))),
+            jnp.pad(d2d[off : off + take], ((0, pad), (0, 0)), constant_values=1.0),
+            jnp.concatenate([uids[off : off + take], c + jnp.arange(pad, dtype=jnp.int32)]),
+        )
+        it = np.asarray(st.iterations, np.int64)
+        active += int(it[:take].sum())
+        loop += int(it.max()) * size
+        off += take
+    assert rep.active_column_iterations == active
+    assert rep.loop_column_iterations == loop > active > 0
+    assert metrics.value("deploy.loop_column_iterations") == loop
+    assert metrics.value("deploy.active_column_iterations") == active
+    (span,) = [e for e in trace.events() if e["name"] == "deploy" and e["ph"] == "X"][-1:]
+    assert span["args"]["columns"] == c
+    assert span["args"]["loop_column_iterations"] == loop
+    assert span["args"]["active_column_iterations"] == active
 
 
 # ----------------------------------------------- scheduler instrumentation
