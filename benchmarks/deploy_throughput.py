@@ -88,7 +88,7 @@ def _deploy_baseline_eager(params, cfg: WVConfig, seed: int = 1) -> DeployReport
         k_d2d, _, _ = jax.random.split(k, 3)
         d2d = dev_mod.sample_d2d(k_d2d, cols.shape, cfg.device)
         _, stats = program_columns(k, cols, cfg, cost=cost, d2d=d2d)
-        report.merge(name, stats, cfg.n_cells)
+        report.merge(name, stats, cfg)
     return report
 
 

@@ -52,7 +52,7 @@ from . import device as dev_mod
 from . import rng
 from .cost import CircuitCost
 from .types import FaultConfig, WVConfig
-from .wv import WVStats, program_columns
+from .wv import WVStats, ladder_loop_work, program_columns
 
 __all__ = [
     "bucket_sizes",
@@ -60,6 +60,7 @@ __all__ = [
     "program_packed_columns",
     "sample_d2d_for",
     "host_fetch",
+    "loop_work",
     "compile_count",
     "host_sync_count",
     "reset_counters",
@@ -225,16 +226,22 @@ def sample_d2d_for(key, col_ids, shape, dev_cfg):
     return dev_mod.sample_d2d(k_d2d, shape, dev_cfg)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _loop_work(iterations: jax.Array, take: int, shards: int) -> jax.Array:
-    """One bucket's WV-loop work as int32 ``[active, loop]``: `active` sums
-    the iterations of its `take` real columns; `loop` is the loop's trip
-    count (its slowest column's iterations, filler included) times the
-    columns the loop carries, per shard.  ``active / loop`` is the loop's
-    occupancy: the share of its column-trips spent on unfinished columns."""
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def loop_work(
+    iterations: jax.Array, take: int, shards: int, max_fine_iters: int
+) -> jax.Array:
+    """One bucket's WV-loop work as int32 ``[active, loop, compactions]``:
+    `active` sums the iterations of its `take` real columns; `loop` is
+    the column-trips its loops carried, Σ over each shard's ladder
+    stages of trips x capacity (`wv.ladder_loop_work`, filler
+    included); `compactions` counts the compacted stages that ran.
+    ``active / loop`` is the loop's occupancy: the share of its
+    column-trips spent on unfinished columns."""
     it = iterations.astype(jnp.int32)
-    trips = jnp.max(it.reshape(shards, -1), axis=1)
-    return jnp.stack([jnp.sum(it[:take]), jnp.sum(trips) * (it.shape[0] // shards)])
+    work, shrinks = jax.vmap(lambda x: ladder_loop_work(x, max_fine_iters))(
+        it.reshape(shards, -1)
+    )
+    return jnp.stack([jnp.sum(it[:take]), jnp.sum(work), jnp.sum(shrinks)])
 
 
 def program_packed_columns(
@@ -256,6 +263,11 @@ def program_packed_columns(
     list[dev_mod.FaultMap] | list[None], jax.Array,
 ]:
     """Program many packed column blocks in a few bucketed dispatches.
+
+    Each bucket is one dispatch of `program_columns` with per-column
+    streams, so a bucket of at least ``2 * wv.COMPACT_FLOOR`` columns (per
+    shard) compacts its unfinished columns into halving ladder stages
+    inside that dispatch; smaller buckets run one loop.
 
     Args:
       key: master PRNG key (column sub-streams derive from it).
@@ -280,10 +292,10 @@ def program_packed_columns(
         persist it alongside d2d.
 
     Returns (g_blocks, stats_blocks, d2d_blocks, fault_blocks,
-    loop_work): the first four split back to the input block boundaries
+    works): the first four split back to the input block boundaries
     (`fault_blocks` is a list of None when no fault config is given);
-    `loop_work` is a (buckets, 2) int32 array of each bucket's
-    ``[active, loop]`` column-iterations (`_loop_work`).  Everything
+    `works` is a (buckets, 3) int32 array of each bucket's
+    ``[active, loop, compactions]`` (`loop_work`).  Everything
     stays on device; no host syncs.  The `deploy.dispatch` span covers
     the d2d draw and the dispatches, and ends when they are enqueued,
     not when the device finishes them.
@@ -293,7 +305,7 @@ def program_packed_columns(
     sizes = [int(b.shape[0]) for b in blocks]
     c_total = sum(sizes)
     if c_total == 0:
-        return [], [], [], [], jnp.zeros((0, 2), jnp.int32)
+        return [], [], [], [], jnp.zeros((0, 3), jnp.int32)
     n = int(blocks[0].shape[1])
     sizes_plan = bucket_sizes(c_total, min_bucket, max_bucket)
     # A bucket's columns split into equal contiguous shards, one WV loop
@@ -371,10 +383,12 @@ def program_packed_columns(
             g_b, st_b = fn(key, tb, db, ub, *fargs)
             g_parts.append(g_b[:take])
             stat_parts.append(jax.tree.map(lambda x: x[:take], st_b))
-            loop_parts.append(_loop_work(st_b.iterations, take, shards))
+            loop_parts.append(
+                loop_work(st_b.iterations, take, shards, cfg.max_fine_iters)
+            )
             off += take
 
-        loop_work = jnp.stack(loop_parts)
+        works = jnp.stack(loop_parts)
         g_all = jnp.concatenate(g_parts) if len(g_parts) > 1 else g_parts[0]
         stats_all = (
             jax.tree.map(lambda *xs: jnp.concatenate(xs), *stat_parts)
@@ -392,4 +406,4 @@ def program_packed_columns(
                 if with_fault else None
             )
             off += c_i
-    return g_blocks, stats_blocks, d2d_blocks, fault_blocks, loop_work
+    return g_blocks, stats_blocks, d2d_blocks, fault_blocks, works

@@ -103,11 +103,13 @@ class DeployReport:
     total_gave_up_cells: float = 0.0  # cells declared unprogrammable
     total_retry_pulses: float = 0.0   # pulses burned on gave-up cells
     remapped_columns: int = 0         # primaries repaired onto spares
-    # The WV loops' occupancy (`pipeline._loop_work`): iterations of the
-    # columns while still being programmed, against trips x columns of
-    # every loop that carried them (bucket filler included).
+    # The WV loops' occupancy (`pipeline.loop_work`): iterations of the
+    # columns while still being programmed, against trips x columns
+    # carried by every ladder stage of every loop (bucket filler
+    # included), and the compacted stages that ran.
     active_column_iterations: int = 0
     loop_column_iterations: int = 0
+    loop_compactions: int = 0
     leaves: dict[str, dict[str, float]] = dataclasses.field(default_factory=dict)
 
     @staticmethod
@@ -177,9 +179,9 @@ class DeployReport:
         """The report from the host copy of `reductions`' tree."""
         agg_h, per_h, rem_h, loops_h, _ = fetched
         # Per-bucket int32 values, summed on the host without overflow.
-        active, loop = sum(
+        active, loop, compactions = sum(
             (np.asarray(w, np.int64).sum(axis=0) for w in loops_h),
-            np.zeros(2, np.int64),
+            np.zeros(3, np.int64),
         )
         stats = list(leaf_stats.values())
         report = cls(
@@ -188,6 +190,7 @@ class DeployReport:
             remapped_columns=int(sum(float(v) for v in rem_h.values())),
             active_column_iterations=int(active),
             loop_column_iterations=int(loop),
+            loop_compactions=int(compactions),
             **{k: float(v) for k, v in agg_h.items()},
         )
         report.leaves = {
@@ -201,17 +204,21 @@ class DeployReport:
             report.leaves[name]["remapped_columns"] = float(v)
         return report
 
-    def merge(self, name: str, stats: WVStats, n_cells: int) -> None:
-        c = int(stats.iterations.shape[0])
+    def merge(self, name: str, stats: WVStats, wv_cfg: WVConfig) -> None:
+        c, n_cells = int(stats.iterations.shape[0]), wv_cfg.n_cells
         lat = float(jnp.sum(stats.latency_ns))
         crit = float(jnp.max(stats.latency_ns))
         en = float(jnp.sum(stats.energy_pj))
         it = float(jnp.mean(stats.iterations))
         rms = float(jnp.sqrt(jnp.mean(stats.rms_error_lsb**2)))
-        # One leaf is one loop with no filler: its trips are its slowest
-        # column's iterations.
-        self.active_column_iterations += int(jnp.sum(stats.iterations))
-        self.loop_column_iterations += c * int(jnp.max(stats.iterations))
+        # One leaf is one dispatch with no filler, counted as a bucket is.
+        active, loop, compactions = np.asarray(
+            pipeline.loop_work(stats.iterations, c, 1, wv_cfg.max_fine_iters),
+            np.int64,
+        )
+        self.active_column_iterations += int(active)
+        self.loop_column_iterations += int(loop)
+        self.loop_compactions += int(compactions)
         self.total_reads += float(jnp.sum(stats.reads))
         self.total_write_pulses += float(jnp.sum(stats.write_pulses))
         self.total_gave_up_cells += float(jnp.sum(stats.gave_up))
@@ -416,6 +423,7 @@ def _fold_deploy(report: "DeployReport", wv_cfg: WVConfig, cost: CircuitCost) ->
             # The WV loops' occupancy: active over loop column-iterations.
             "active_column_iterations": report.active_column_iterations,
             "loop_column_iterations": report.loop_column_iterations,
+            "loop_compactions": report.loop_compactions,
         },
         prefix="deploy.",
     )
@@ -581,12 +589,12 @@ def deploy_arrays(
     host sync, with give-up/remap accounting riding it.
 
     Spans (DESIGN.md Sec. 14): `deploy` covers the whole call, and its
-    args carry `columns`, `active_column_iterations` and
-    `loop_column_iterations`.  Its children are `deploy.plan` (quantize
-    and pack), `deploy.dispatch` (the d2d draw and the bucket
-    dispatches), `deploy.report` (the health tree and the report's
-    reductions), `deploy.sync` (the host fetch's wait for the device)
-    and `deploy.fold` (everything after the sync).
+    args carry `columns`, `active_column_iterations`,
+    `loop_column_iterations` and `loop_compactions`.  Its children are
+    `deploy.plan` (quantize and pack), `deploy.dispatch` (the d2d draw
+    and the bucket dispatches), `deploy.report` (the health tree and the
+    report's reductions), `deploy.sync` (the host fetch's wait for the
+    device) and `deploy.fold` (everything after the sync).
     """
     if q_cfg is None:
         q_cfg = QuantConfig(
@@ -751,7 +759,7 @@ def deploy_arrays(
             for plan in plans:
                 state, stats = _program_plan(key, plan, wv_cfg, cost)
                 with obs.span("deploy.sync", cat="deploy"):
-                    report.merge(plan.name, stats, wv_cfg.n_cells)
+                    report.merge(plan.name, stats, wv_cfg)
                 arrays[plan.name] = state
             with obs.span("deploy.fold", cat="deploy"):
                 _fold_deploy(report, wv_cfg, cost)
@@ -759,6 +767,7 @@ def deploy_arrays(
         sp["rms_cell_error_lsb"] = report.rms_cell_error_lsb
         sp["active_column_iterations"] = report.active_column_iterations
         sp["loop_column_iterations"] = report.loop_column_iterations
+        sp["loop_compactions"] = report.loop_compactions
     return (
         DeployedModel(
             treedef=treedef, leaves=leaves, slots=slots, arrays=arrays,

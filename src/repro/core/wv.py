@@ -20,10 +20,15 @@ basis x converter x averaging matrix (`readout.for_wv_method`), and this
 module only owns the key schedule, the decision logic on the returned
 measurements, and the write phase.
 
-The engine runs ONE `lax.while_loop` over WV iterations for an arbitrary
+The engine runs a `lax.while_loop` over WV iterations for an arbitrary
 batch of columns simultaneously, with per-cell freeze masks (streak
 counter, Sec. 3.1) and per-column active masks — the idiomatic way to
 batch heterogeneous convergence on SPMD hardware (no vmap-of-while).
+With per-column streams the loop is a short static ladder of such loops
+over halving column capacities (`compaction_ladder`): between stages
+the unfinished columns are gathered into the next, smaller batch, so
+the convergence tail stops paying for finished columns (DESIGN.md
+Sec. 10).
 
 Physical modelling notes:
 * Verify reads always sense the WHOLE column (frozen cells keep
@@ -43,6 +48,7 @@ Shapes: targets (C, N) float32 integer levels; returns g (C, N) and a
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -57,7 +63,18 @@ from . import rng
 from .cost import CircuitCost, write_phase_cost
 from .types import WVConfig, WVMethod
 
-__all__ = ["WVStats", "program_columns", "verify_aggregate", "verify_sweep"]
+__all__ = [
+    "WVStats",
+    "compaction_ladder",
+    "ladder_loop_work",
+    "program_columns",
+    "verify_aggregate",
+    "verify_sweep",
+]
+
+# Smallest batch the staged fine loop compacts into: below it a stage's
+# gathers cost more than the trips they save.
+COMPACT_FLOOR = 2048
 
 
 class WVStats(NamedTuple):
@@ -182,6 +199,59 @@ def _characterized_coarse_pulses(
     return jnp.argmin(err, axis=0).astype(jnp.float32)
 
 
+def compaction_ladder(c: int) -> tuple[int, ...]:
+    """Column capacities of the staged fine loop for a C-column batch.
+
+    ``C, C/2, C/4, ...`` while the next capacity stays at or above
+    ``max(C/16, COMPACT_FLOOR)``; a one-entry ladder is the single loop
+    (every batch under ``2 * COMPACT_FLOOR`` columns).  A pure function
+    of the shape, so it is static under jit.
+    """
+    floor = max(c // 16, COMPACT_FLOOR)
+    caps = [c]
+    while caps[-1] // 2 >= floor:
+        caps.append(caps[-1] // 2)
+    return tuple(caps)
+
+
+def ladder_loop_work(
+    iterations: jax.Array, max_fine_iters: int
+) -> tuple[jax.Array, jax.Array]:
+    """Column-trips the staged fine loop carried, and its compactions.
+
+    `iterations` is one loop's per-column `WVStats.iterations` (C,).
+    Stage s runs while more than ``caps[s+1]`` columns are unfinished,
+    so it ends at the first trip t with at most that many columns of
+    more than t iterations; the last stage ends when none is left.
+    Returns int32 scalars: Σ over stages of trips x capacity, and the
+    number of compacted stages that ran at least one trip.  (Under a
+    give-up budget a sweep in which a column's last unfrozen cells all
+    exhaust is not counted in its iterations, so there the trips can be
+    short by one per such sweep.)
+    """
+    caps = compaction_ladder(int(iterations.shape[0]))
+    trips = jnp.arange(max_fine_iters + 1, dtype=jnp.int32)
+    # left[t]: columns unfinished after t trips, non-increasing in t.
+    left = jnp.sum(iterations.astype(jnp.int32)[None, :] > trips[:, None], axis=1)
+    ends = [jnp.sum(left > k) for k in caps[1:] + (0,)]
+    work = ends[0] * caps[0]
+    shrinks = jnp.asarray(0, jnp.int32)
+    for cap, start, end in zip(caps[1:], ends[:-1], ends[1:]):
+        work = work + (end - start) * cap
+        shrinks = shrinks + (end > start).astype(jnp.int32)
+    return work, shrinks
+
+
+class _ColInputs(NamedTuple):
+    """Per-column inputs of the fine loop (rows gathered on compaction)."""
+
+    targets: jax.Array
+    d2d: jax.Array
+    k_loop: jax.Array
+    col_offset: jax.Array | None
+    fault: dev_mod.FaultMap | None
+
+
 class _LoopState(NamedTuple):
     g: jax.Array
     streak: jax.Array
@@ -229,6 +299,16 @@ def program_columns(
         Sampled caller-side (like `d2d`) so refresh re-programs under
         the same silicon.  The verify key schedule is unconditional, so
         `fault=None` and an inert map are bit-identical.
+
+    Compaction (DESIGN.md Sec. 10): with `col_ids` the fine loop runs
+    as the static ladder of `compaction_ladder(C)`: stage s loops until
+    at most the next stage's capacity of columns is unfinished, then the
+    unfinished rows (state, targets, d2d, keys, offsets, fault rows) are
+    gathered into that capacity and the stage's rows scattered back to
+    full-size outputs.  The trip counter and `max_fine_iters` stay
+    global, so every column sees the trips and draws it would see in
+    one loop; the result is bit-identical to it.  Batches under
+    ``2 * COMPACT_FLOOR`` columns, and the legacy path, run one loop.
 
     Give-up (DESIGN.md Sec. 15): with `cfg.give_up_pulses` set, a cell
     whose cumulative fine-pulse count reaches the budget at the start of
@@ -290,8 +370,8 @@ def program_columns(
     # the compiled decision stream is unchanged.
     budget = cfg.give_up_pulses
 
-    def body(st: _LoopState) -> _LoopState:
-        k_it = rng.fold_in(k_loop, st.it)
+    def body(inp: _ColInputs, st: _LoopState) -> _LoopState:
+        k_it = rng.fold_in(inp.k_loop, st.it)
         k_v, k_w = rng.split(k_it)
 
         if budget is not None:
@@ -307,7 +387,7 @@ def program_columns(
         col_active = ~jnp.all(frozen_in, axis=-1)  # (C,)
 
         agg, dev_mag, n_cmp, thr = verify_aggregate(
-            k_v, st.g, targets, cfg, col_offset
+            k_v, st.g, inp.targets, cfg, inp.col_offset
         )
         can_freeze = st.it >= warmup
 
@@ -327,7 +407,7 @@ def program_columns(
             # weak/tile-degraded cells need no kernel change; stuck cells
             # are re-pinned after the update (same association as the
             # unfused apply_pulses path -> still bit-identical).
-            d2d_eff = d2d if fault is None else d2d * fault.efficiency
+            d2d_eff = inp.d2d if inp.fault is None else inp.d2d * inp.fault.efficiency
 
             def upd(cf: bool):
                 p = WVCellParams(
@@ -350,7 +430,7 @@ def program_columns(
             g, streak, frozen, n_p, direction = jax.lax.cond(
                 can_freeze, lambda: upd(True), lambda: upd(False)
             )
-            g = dev_mod.clamp_stuck(g, fault)
+            g = dev_mod.clamp_stuck(g, inp.fault)
         else:
             decision = _threshold(agg, thr)
             # Streak / freeze (Sec. 3.1): K consecutive in-threshold
@@ -374,7 +454,7 @@ def program_columns(
             direction = jnp.where(act_cell, -decision, 0.0)  # too high -> RESET
 
             g_new = dev_mod.apply_pulses(
-                k_w, st.g, direction, n_p, d2d, dev_cfg, fault=fault
+                k_w, st.g, direction, n_p, inp.d2d, dev_cfg, fault=inp.fault
             )
             g = jnp.where(col_active[:, None], g_new, st.g)
 
@@ -401,6 +481,15 @@ def program_columns(
     def cond(st: _LoopState) -> jax.Array:
         return (st.it < cfg.max_fine_iters) & jnp.any(~st.frozen)
 
+    def stage_cond(nxt: int | None):
+        """A ladder stage runs until at most `nxt` columns are unfinished;
+        the last stage (`nxt` None) until none is."""
+        if nxt is None:
+            return cond
+        return lambda st: (st.it < cfg.max_fine_iters) & (
+            jnp.sum(~jnp.all(st.frozen, axis=-1)) > nxt
+        )
+
     zero = jnp.zeros((c,), jnp.float32)
     init = _LoopState(
         g=g,
@@ -415,7 +504,37 @@ def program_columns(
         cell_pulses=jnp.zeros(targets.shape, jnp.float32),
         gave_up=jnp.zeros(targets.shape, bool),
     )
-    st = jax.lax.while_loop(cond, body, init)
+    inp = _ColInputs(targets, d2d, k_loop, col_offset, fault)
+    # The legacy batch-shaped draws tie a column's noise to its batch, so
+    # only per-column streams may compact.
+    caps = (c,) if col_ids is None else compaction_ladder(c)
+    nexts = caps[1:] + (None,)
+    st = out = jax.lax.while_loop(
+        stage_cond(nexts[0]), functools.partial(body, inp), init
+    )
+    pos = jnp.arange(c, dtype=jnp.int32) if len(caps) > 1 else None
+    for cap, nxt in zip(caps[1:], nexts[1:]):
+        # Compaction: gather the unfinished rows into `cap` slots.  A
+        # finished column's state never changes again (its cells are all
+        # frozen) and every draw comes from its own stream at the global
+        # trip `it`, so dropping it is exact.  Filler slots are frozen
+        # copies of row 0 that scatter nowhere (index c, dropped).
+        unfinished = ~jnp.all(st.frozen, axis=-1)
+        (rows,) = jnp.nonzero(unfinished, size=cap, fill_value=0)
+        filler = jnp.arange(cap) >= jnp.sum(unfinished)
+        take = lambda x: x[rows]  # noqa: E731
+        inp = jax.tree.map(take, inp)
+        rows_st = jax.tree.map(take, st._replace(it=None))
+        st = rows_st._replace(it=st.it, frozen=rows_st.frozen | filler[:, None])
+        pos = jnp.where(filler, c, pos[rows])
+        st = jax.lax.while_loop(stage_cond(nxt), functools.partial(body, inp), st)
+        # Every carried row's state as this stage left it; a later stage
+        # overwrites the rows it carries on.
+        out = jax.tree.map(
+            lambda o, x: o.at[pos].set(x, mode="drop"),
+            out._replace(it=None), st._replace(it=None),
+        )._replace(it=st.it)
+    st = out
 
     if budget is not None:
         # Cells still unfrozen at max_fine_iters never converged either.

@@ -4,7 +4,8 @@ Covers the ISSUE-2 contracts: bucketed-vs-per-leaf bit-identity, fused
 Pallas wv_step-in-loop parity with the unfused engine and the ref
 oracle, no-retrace bucketing (compiles <= buckets), the single-host-sync
 stats path, the scalar coarse-pulse scan, and statistical equivalence of
-the per-column RNG policy with the legacy batch-shaped draws.
+the per-column RNG policy with the legacy batch-shaped draws, and the
+exactness of the fine loop's active-column compaction.
 """
 
 import jax
@@ -14,7 +15,7 @@ import pytest
 
 from repro.core import WVConfig, WVMethod, pipeline, program_columns
 from repro.core.programmer import deploy_arrays, deploy_params
-from repro.core.types import DeviceConfig
+from repro.core.types import DeviceConfig, FaultConfig
 
 
 @pytest.fixture(scope="module")
@@ -176,6 +177,51 @@ def test_pallas_wv_step_in_loop_parity(method):
     np.testing.assert_allclose(
         np.asarray(s0.energy_pj), np.asarray(s1.energy_pj), rtol=1e-5
     )
+
+
+_COMPACT_CASES = [
+    (method, faults, pallas)
+    for method in (WVMethod.HARP, WVMethod.HD_PV, WVMethod.MRA, WVMethod.CW_SC)
+    for faults, pallas in ((False, False), (True, False), (True, True))
+]
+
+
+@pytest.mark.parametrize(
+    "method,faults,pallas", _COMPACT_CASES,
+    ids=[f"{m.value}-{'faults' if f else 'clean'}-{'pallas' if p else 'jnp'}"
+         for m, f, p in _COMPACT_CASES],
+)
+def test_compacted_bucket_bit_identical(method, faults, pallas):
+    """An 8,192-column bucket runs the staged loop (8192 -> 4096 -> 2048
+    columns); the same uids in 256-column buckets run the single loop.
+    Per-column streams and invariant frozen columns make every
+    conductance and every WVStats field bit-identical, with a fault map
+    and a give-up budget as without, fused kernel or not."""
+    cfg = WVConfig(
+        method=method, use_pallas=pallas,
+        give_up_pulses=40 if faults else None,
+    )
+    fc = (
+        FaultConfig(p_stuck_hrs=0.01, p_stuck_lrs=0.005, p_weak=0.02)
+        if faults else None
+    )
+    t = jax.random.randint(jax.random.PRNGKey(17), (8192, 32), 0, 8).astype(
+        jnp.float32
+    )
+    key = jax.random.PRNGKey(18)
+    out = {}
+    for bucket in (8192, 256):
+        g, st, _, _, works = pipeline.program_packed_columns(
+            key, [t], cfg, min_bucket=bucket, max_bucket=bucket, fault_cfg=fc,
+        )
+        out[bucket] = (g[0], st[0], np.asarray(works))
+    (g_c, st_c, w_c), (g_s, st_s, w_s) = out[8192], out[256]
+    assert w_c[0, 2] >= 1 and w_s[:, 2].sum() == 0  # the ladder engaged
+    if faults:
+        assert float(jnp.sum(st_c.gave_up)) > 0  # the budget bit
+    np.testing.assert_array_equal(np.asarray(g_c), np.asarray(g_s))
+    for field, a, b in zip(st_c._fields, st_c, st_s):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), field)
 
 
 def test_event_mode_noise_parity():

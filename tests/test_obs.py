@@ -26,7 +26,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro import obs
-from repro.core import CircuitCost, WVConfig, WVMethod, pipeline
+from repro.core import CircuitCost, WVConfig, WVMethod, pipeline, program_columns
 from repro.core.programmer import deploy_arrays
 from repro.models import ModelConfig, init_params
 from repro.obs import ledger, metrics, report, trace
@@ -251,16 +251,51 @@ def test_deploy_spans_on_the_profiler_timeline(tmp_path):
         assert abs(e.duration_ns / 1e3 - recorded[name]["dur"]) < 1e3, name
 
 
-@pytest.mark.parametrize("method", [WVMethod.HARP, WVMethod.MRA])
-def test_deploy_loop_occupancy_counters(method):
+def _staged_loop_work(it: np.ndarray) -> tuple[int, int]:
+    """Column-trips and compactions of one bucket loop, replayed from its
+    per-column iterations: capacities halve from C while the next stays
+    at or above max(C/16, 2048), and a stage runs while more columns are
+    unfinished than the next stage holds (none, for the last)."""
+    c = it.shape[0]
+    caps = [c]
+    while caps[-1] // 2 >= max(c // 16, 2048):
+        caps.append(caps[-1] // 2)
+    work = compactions = t = 0
+    for s, cap in enumerate(caps):
+        nxt = caps[s + 1] if s + 1 < len(caps) else 0
+        start = t
+        while t < it.max() and int((it > t).sum()) > nxt:
+            t += 1
+        work += (t - start) * cap
+        compactions += int(s > 0 and t > start)
+    return work, compactions
+
+
+_OCCUPANCY_CASES = [
+    (m, b) for b in (256, 8192) for m in (WVMethod.HARP, WVMethod.MRA)
+]
+
+
+@pytest.mark.parametrize(
+    "method,bucket", _OCCUPANCY_CASES,
+    ids=[m.value if b == 256 else f"{m.value}-{b}" for m, b in _OCCUPANCY_CASES],
+)
+def test_deploy_loop_occupancy_counters(method, bucket):
     """The loop counters ride the deploy's one host sync: `loop` is each
-    bucket's trips (its slowest column, filler included) times its
-    columns, `active` the real columns' iterations, both equal to the
-    buckets' loops run again through the shared dispatch.  (HARP's
-    loops run to the 50-trip cap here, MRA's end before it.)"""
-    params = _tiny_params()
+    bucket's column-trips, Σ over its ladder stages of trips x the
+    stage's columns (filler included), `active` the real columns'
+    iterations, and `deploy.loop_compactions` the compacted stages that
+    ran, all equal to the buckets' loops run again through the shared
+    dispatch.  256-column buckets run one loop (trips = the slowest
+    column's iterations; HARP's run to the 50-trip cap here, MRA's end
+    before it); one 8,192-column bucket compacts, which lifts its
+    occupancy above the single loop's."""
+    if bucket == 256:
+        params = _tiny_params()  # 448 columns: 64 filler
+    else:
+        params = {"wc": jax.random.normal(jax.random.PRNGKey(4), (128, 512)) * 0.02}
     wv = WVConfig(method=method, max_coarse_iters=3)
-    kw = dict(min_bucket=256, max_bucket=256)  # 448 columns: 64 filler
+    kw = dict(min_bucket=bucket, max_bucket=bucket)
     key = jax.random.PRNGKey(2)
     deploy_arrays(jax.random.PRNGKey(3), params, wv, **kw)  # warm
     pipeline.reset_counters()
@@ -275,7 +310,7 @@ def test_deploy_loop_occupancy_counters(method):
     c = int(uids.shape[0])
     d2d = pipeline.sample_d2d_for(key, uids, targets.shape, wv.device)
     fn = pipeline.get_program_fn(wv, CircuitCost())
-    active = loop = off = 0
+    active = loop = single = compactions = off = 0
     for size in pipeline.bucket_sizes(c, **kw):
         take = min(size, c - off)
         pad = size - take
@@ -287,16 +322,68 @@ def test_deploy_loop_occupancy_counters(method):
         )
         it = np.asarray(st.iterations, np.int64)
         active += int(it[:take].sum())
-        loop += int(it.max()) * size
+        work, shrinks = _staged_loop_work(it)
+        loop += work
+        compactions += shrinks
+        single += int(it.max()) * size
         off += take
     assert rep.active_column_iterations == active
     assert rep.loop_column_iterations == loop > active > 0
+    assert rep.loop_compactions == compactions
+    if bucket == 256:
+        assert loop == single and compactions == 0
+    else:
+        assert compactions >= 1 and active / loop > active / single
     assert metrics.value("deploy.loop_column_iterations") == loop
     assert metrics.value("deploy.active_column_iterations") == active
+    assert metrics.value("deploy.loop_compactions") == compactions
     (span,) = [e for e in trace.events() if e["name"] == "deploy" and e["ph"] == "X"][-1:]
     assert span["args"]["columns"] == c
     assert span["args"]["loop_column_iterations"] == loop
     assert span["args"]["active_column_iterations"] == active
+    assert span["args"]["loop_compactions"] == compactions
+
+
+def test_loop_work_counts_each_shards_ladder():
+    """Under a mesh every device runs its own shard's ladder, so a
+    bucket's loop work is the sum of its shards' replayed ladders."""
+    rng = np.random.default_rng(0)
+    it = np.minimum(rng.geometric(0.08, size=2 * 8192), 50).astype(np.float32)
+    want = [_staged_loop_work(half) for half in np.split(it, 2)]
+    got = np.asarray(pipeline.loop_work(jnp.asarray(it), 8000, 2, 50))
+    assert got.tolist() == [
+        int(it[:8000].sum()), sum(w for w, _ in want), sum(k for _, k in want),
+    ]
+    assert got[2] >= 2  # both shards compacted
+
+
+def test_loop_work_equals_trips_run():
+    """The counter is what the staged loop ran: the engine's loops,
+    executed one trip at a time on the host, carry exactly the
+    column-trips and compactions `pipeline.loop_work` derives from the
+    iterations (4,096 columns: stages of 4,096 and 2,048)."""
+    ran = []
+
+    def counted(cond, body, carry):
+        trips = 0
+        while bool(cond(carry)):
+            carry, trips = body(carry), trips + 1
+        ran.append((trips, carry.g.shape[0]))
+        return carry
+
+    cfg = WVConfig(method=WVMethod.HARP)
+    t = jax.random.randint(jax.random.PRNGKey(5), (4096, 32), 0, 8).astype(jnp.float32)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax.lax, "while_loop", counted)
+        _, st = program_columns(
+            jax.random.PRNGKey(6), t, cfg, col_ids=jnp.arange(4096, dtype=jnp.int32)
+        )
+    assert [cap for _, cap in ran] == [4096, 2048]
+    _, loop, compactions = np.asarray(
+        pipeline.loop_work(st.iterations, 4096, 1, cfg.max_fine_iters)
+    ).tolist()
+    assert loop == sum(trips * cap for trips, cap in ran)
+    assert compactions == int(ran[1][0] > 0) == 1
 
 
 # ----------------------------------------------- scheduler instrumentation
