@@ -382,7 +382,7 @@ def _deploy_health_tree(
     (extra=...)` — building it never synchronizes (DESIGN.md Sec. 16).
     """
     cpt = (fault_cfg or FaultConfig()).columns_per_tile
-    tile_ids, tiles = obs.health.tile_deploy_stats(
+    tiles = obs.health.tile_deploy_stats(
         stats_map, uids_map, cpt, extra_columns=extra_columns
     )
     stats = list(stats_map.values())
@@ -393,18 +393,25 @@ def _deploy_health_tree(
         (_PULSE_DIGEST, pulses), (_ITER_DIGEST, iters),
     ):
         digs[name] = obs.StreamingDigest.zeros(lo, hi, nb).add(vals)
-    return {"tile_ids": tile_ids, "tiles": tiles, "digests": digs}
+    return {"tiles": tiles, "digests": digs}
 
 
-def _fold_deploy_health(extra_h: dict[str, Any] | None) -> None:
-    """Fold the FETCHED health tree into the host registries."""
-    if not extra_h:
-        return
-    tile_ids = extra_h["tile_ids"]
-    for metric, vals in extra_h["tiles"].items():
+def _fold_deploy_health(extra_h: dict[str, Any]) -> dict[str, int]:
+    """Fold the FETCHED health tree into the host registries.
+
+    Also counts, under `deploy.`, `health_tile_range` (the tiles of the
+    dense range the device reduced) and `health_tiles` (those that held
+    a column), and returns both."""
+    tiles_h = extra_h["tiles"]
+    tile_ids, tiles = obs.health.present_tiles(tiles_h)
+    for metric, vals in tiles.items():
         obs.health_registry.fold_tiles(f"deploy.{metric}", tile_ids, vals)
     for name, dig in extra_h["digests"].items():
         obs.digests.fold(name, dig)
+    n_range = int(np.size(tiles_h["sums"]["columns"])) if tiles_h else 0
+    counts = {"health_tile_range": n_range, "health_tiles": len(tile_ids)}
+    obs.registry.fold(counts, prefix="deploy.")
+    return counts
 
 
 def _fold_deploy(report: "DeployReport", wv_cfg: WVConfig, cost: CircuitCost) -> None:
@@ -590,11 +597,14 @@ def deploy_arrays(
 
     Spans (DESIGN.md Sec. 14): `deploy` covers the whole call, and its
     args carry `columns`, `active_column_iterations`,
-    `loop_column_iterations` and `loop_compactions`.  Its children are
-    `deploy.plan` (quantize and pack), `deploy.dispatch` (the d2d draw
-    and the bucket dispatches), `deploy.report` (the health tree and the
-    report's reductions), `deploy.sync` (the host fetch's wait for the
-    device) and `deploy.fold` (everything after the sync).
+    `loop_column_iterations`, `loop_compactions`, `health_tile_range`
+    and `health_tiles` (the tiles the health maps reduced over, and
+    those that held a column; both also `deploy.*` counters).  Its
+    children are `deploy.plan` (quantize and pack), `deploy.dispatch`
+    (the d2d draw and the bucket dispatches), `deploy.report` (the uid
+    bounds and upload, the health tree's and the report's reductions,
+    dispatched), `deploy.sync` (the host fetch's wait for the device)
+    and `deploy.fold` (everything after the sync).
     """
     if q_cfg is None:
         q_cfg = QuantConfig(
@@ -752,9 +762,10 @@ def deploy_arrays(
                 # reductions and deploy digests were fetched BY the
                 # report's single host sync; folding them is pure host
                 # work.
-                _fold_deploy_health(fetched[-1])
+                tile_counts = _fold_deploy_health(fetched[-1])
                 _fold_deploy(report, wv_cfg, cost)
         else:
+            tile_counts = {"health_tile_range": 0, "health_tiles": 0}
             report = DeployReport()
             for plan in plans:
                 state, stats = _program_plan(key, plan, wv_cfg, cost)
@@ -768,6 +779,7 @@ def deploy_arrays(
         sp["active_column_iterations"] = report.active_column_iterations
         sp["loop_column_iterations"] = report.loop_column_iterations
         sp["loop_compactions"] = report.loop_compactions
+        sp.update(tile_counts)
     return (
         DeployedModel(
             treedef=treedef, leaves=leaves, slots=slots, arrays=arrays,

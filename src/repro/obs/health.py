@@ -9,8 +9,11 @@ still meet its objectives" from signals the stack already produces
   The tile axis is tiny (columns / columns_per_tile), so the per-tile
   arrays ride the host syncs the paths already perform: the deploy's
   single `host_fetch` (`DeployReport.reductions`) and the scrub's drift
-  fetch.  Column->tile assignment comes from the deploy's physical
-  column uids (host numpy), so no device work is needed to route it.
+  fetch.  A deploy routes column->tile on the device: one jitted
+  segment sum per metric over the dense tile range its physical column
+  uids span (`uid // columns_per_tile`, offset by the range's first
+  tile); after the fetch, `present_tiles` drops the range's empty tiles
+  on the host.
 * **Host-side registry** — `HealthRegistry` folds the fetched per-tile
   values into named maps (give-up density, retry pulses, drift RMS,
   remapped columns) plus scalar gauges (refresh debt, scrub backlog).
@@ -27,13 +30,18 @@ it never touches this module's live state.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Mapping, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import ops as jops
 
 __all__ = [
     "tile_reduce",
     "tile_deploy_stats",
+    "present_tiles",
     "HealthRegistry",
     "health",
     "SLORule",
@@ -47,13 +55,10 @@ __all__ = [
 def tile_reduce(values, tile_inv, num_tiles: int):
     """Segment-sum per-column `values` into `num_tiles` tile bins.
 
-    `tile_inv` is the host-computed (numpy) column->tile-slot index, so
+    `tile_inv` is the column->tile-slot index (host numpy or traced), so
     the only device work is one segment sum — traced-safe and fetchable
     alongside whatever the caller was already fetching.
     """
-    import jax.numpy as jnp
-    from jax import ops as jops
-
     return jops.segment_sum(
         jnp.asarray(values, jnp.float32),
         jnp.asarray(tile_inv, jnp.int32),
@@ -61,56 +66,104 @@ def tile_reduce(values, tile_inv, num_tiles: int):
     )
 
 
+# Deploy tile maps: metric name -> the `WVStats` field it sums
+# (`err2_sum` sums the field's square).
+_DEPLOY_TILE_FIELDS = (
+    ("gave_up_cells", "gave_up"),
+    ("retry_pulses", "retry_pulses"),
+    ("write_pulses", "write_pulses"),
+    ("verify_reads", "reads"),
+    ("err2_sum", "rms_error_lsb"),
+)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("columns_per_tile", "num_tiles")
+)
+def _dense_tile_sums(uids, tile_lo, fields, extra, *, columns_per_tile,
+                     num_tiles):
+    """Per-tile sums over the dense tile range [tile_lo, tile_lo +
+    num_tiles): `fields` and `extra` map metric -> per-leaf column
+    vectors, in the order of `uids`."""
+    seg = uids // columns_per_tile - tile_lo
+    cols = {m: jnp.concatenate(v) for m, v in {**fields, **extra}.items()}
+    cols["err2_sum"] = cols["err2_sum"] ** 2
+    tree = {m: tile_reduce(v, seg, num_tiles) for m, v in cols.items()}
+    tree["columns"] = jops.segment_sum(
+        jnp.ones_like(seg), seg, num_segments=num_tiles
+    )
+    return tree
+
+
 def tile_deploy_stats(
     stats_map: Mapping[str, Any],
     uids_map: Mapping[str, np.ndarray],
     columns_per_tile: int,
     extra_columns: Mapping[str, Mapping[str, Any]] | None = None,
-) -> tuple[np.ndarray, dict[str, Any]]:
+) -> dict[str, Any]:
     """Per-tile deployment health reductions (device-side).
 
-    Returns ``(tile_ids, device_tree)`` where `tile_ids` is the host
-    numpy array of physical tile ids present in this deploy and
-    `device_tree` maps metric name -> per-tile jnp array (same order).
-    The caller appends `device_tree` to an existing fetch; nothing here
-    synchronizes.  `stats_map` values are `WVStats`-shaped (duck-typed:
-    gave_up / retry_pulses / write_pulses / reads / rms_error_lsb per
-    column); `uids_map` holds each leaf's physical column uids.
-    `extra_columns` adds caller-supplied per-column vectors (metric ->
-    leaf name -> (C,) array) reduced with the same tile assignment —
-    e.g. the spare-remap path's per-column remapped flags.
+    Returns a tree for the caller to append to an existing fetch
+    (nothing here synchronizes); `present_tiles` turns its host copy
+    into ``(tile_ids, maps)``.  `stats_map` values are `WVStats`-shaped
+    (duck-typed: gave_up / retry_pulses / write_pulses / reads /
+    rms_error_lsb per column); `uids_map` holds each leaf's physical
+    column uids (below 2**31).  `extra_columns` adds caller-supplied
+    per-column vectors (metric -> leaf name -> (C,) array) reduced with
+    the same tile assignment — e.g. the spare-remap path's per-column
+    remapped flags.
+
+    The host reads only the uids' bounds and uploads them once; the
+    device sums every metric, and a count of columns, over each tile of
+    the dense range [min uid, max uid] // columns_per_tile.  A
+    contiguous deploy fills that range; spare placement spreads uids
+    over its provision of tiles, which bounds the range to a small
+    multiple of the tiles that hold a column.  Tile ids rise with the
+    segment index, so each tile sums its columns in the same order as a
+    sort of the tile ids would give.
     """
-    names = [n for n in stats_map if n in uids_map]
+    names = [
+        n for n in stats_map if n in uids_map and np.size(uids_map[n])
+    ]
     if not names:
-        return np.zeros((0,), np.int64), {}
-    uids = np.concatenate(
-        [np.asarray(uids_map[n], np.int64) for n in names]
-    )
-    tids = uids // int(columns_per_tile)
-    tile_ids, inv = np.unique(tids, return_inverse=True)
-    n_tiles = int(tile_ids.shape[0])
-
-    import jax.numpy as jnp
-
-    def cat(attr):
-        return jnp.concatenate(
-            [jnp.asarray(getattr(stats_map[n], attr)) for n in names]
-        )
-
-    tree = {
-        "gave_up_cells": tile_reduce(cat("gave_up"), inv, n_tiles),
-        "retry_pulses": tile_reduce(cat("retry_pulses"), inv, n_tiles),
-        "write_pulses": tile_reduce(cat("write_pulses"), inv, n_tiles),
-        "verify_reads": tile_reduce(cat("reads"), inv, n_tiles),
-        "err2_sum": tile_reduce(cat("rms_error_lsb") ** 2, inv, n_tiles),
+        return {}
+    leaf_uids = [np.asarray(uids_map[n]) for n in names]
+    u_min = min(int(u.min()) for u in leaf_uids)
+    u_max = max(int(u.max()) for u in leaf_uids)
+    if u_min < 0 or u_max >= 2**31:
+        raise ValueError(f"column uids [{u_min}, {u_max}] exceed int32")
+    uids = np.concatenate(leaf_uids, dtype=np.int32, casting="same_kind")
+    cpt = int(columns_per_tile)
+    tile_lo = u_min // cpt
+    fields = {
+        metric: [getattr(stats_map[n], attr) for n in names]
+        for metric, attr in _DEPLOY_TILE_FIELDS
     }
-    for metric, leaf_vecs in (extra_columns or {}).items():
-        tree[metric] = tile_reduce(
-            jnp.concatenate([jnp.asarray(leaf_vecs[n]) for n in names]),
-            inv, n_tiles,
-        )
-    tree["columns"] = np.bincount(inv, minlength=n_tiles).astype(np.float64)
-    return tile_ids, tree
+    extra = {
+        metric: [leaf_vecs[n] for n in names]
+        for metric, leaf_vecs in (extra_columns or {}).items()
+    }
+    sums = _dense_tile_sums(
+        uids, np.int32(tile_lo), fields, extra,
+        columns_per_tile=cpt, num_tiles=u_max // cpt - tile_lo + 1,
+    )
+    return {"tile_lo": tile_lo, "sums": sums}
+
+
+def present_tiles(tree_h: Mapping[str, Any]) -> tuple[np.ndarray, dict]:
+    """``(tile_ids, maps)`` from the HOST copy of `tile_deploy_stats`'
+    tree: the range's tiles that hold a column, ascending, and each
+    metric's per-tile values at them (`columns` as float64 counts)."""
+    if not tree_h:
+        return np.zeros((0,), np.int64), {}
+    sums = tree_h["sums"]
+    pos = np.flatnonzero(np.asarray(sums["columns"]) > 0)
+    tiles = {
+        metric: np.asarray(v)[pos] for metric, v in sums.items()
+        if metric != "columns"
+    }
+    tiles["columns"] = np.asarray(sums["columns"])[pos].astype(np.float64)
+    return int(tree_h["tile_lo"]) + pos, tiles
 
 
 # -------------------------------------------------------- host-side

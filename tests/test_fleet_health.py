@@ -2,8 +2,10 @@
 
 Tier-1 versions of what benchmarks/fleet_health.py asserts at scale:
 
-* per-tile health maps reduce device-side and ride the deploy's single
-  host sync (no extra fetch for the maps or the deploy digests);
+* per-tile health maps reduce device-side over the deploy's dense tile
+  range, equal bit for bit to a numpy reduction over the present tiles,
+  and ride the deploy's single host sync (no extra fetch for the maps
+  or the deploy digests), on the spare-remap path too;
 * the lifetime scrub populates drift/give-up health state and the
   refresh-debt gauge on its existing epoch sync;
 * declarative SLO rules resolve dotted metric paths (including literal
@@ -18,13 +20,14 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import WVConfig, WVMethod, pipeline
+from repro.core import WVConfig, WVMethod, pipeline, remap
 from repro.core.programmer import deploy_arrays
+from repro.core.wv import WVStats
 from repro.core.types import FaultConfig
 from repro.lifetime import LifetimeSimulator
 from repro.lifetime.refresh import RefreshConfig, RefreshPolicy
 from repro.obs import metrics
-from repro.obs.health import resolve_metric
+from repro.obs.health import present_tiles, resolve_metric, tile_deploy_stats
 
 
 @pytest.fixture(autouse=True)
@@ -97,10 +100,120 @@ def test_fleet_status_joins_namespaces():
 
 
 # ---------------------------------------------------- deploy health maps
+_CPT = 16
+_LEAF_COLS = (40, 72, 9)
+
+
+def _leaf_stats(rng, c):
+    def vec(hi):
+        return jnp.asarray(rng.integers(0, hi, c).astype(np.float32))
+
+    return WVStats(
+        iterations=vec(50), latency_ns=vec(900), energy_pj=vec(900),
+        reads=vec(400), write_pulses=vec(300),
+        rms_error_lsb=jnp.asarray(rng.random(c, dtype=np.float32) * 2.5),
+        frozen_frac=vec(1),
+        gave_up=jnp.asarray(rng.integers(0, 3, c), jnp.int32),
+        retry_pulses=vec(60),
+    )
+
+
+def _placement_uids(counts):
+    fc = FaultConfig(p_stuck_hrs=0.02, columns_per_tile=_CPT, tiles_per_chip=4,
+                     sigma_tile_fault_dec=1.0)
+    return remap.plan_placement(jax.random.PRNGKey(7), counts, fc)
+
+
+def _contiguous_uids(counts, base=0):
+    edges = base + np.cumsum([0, *counts])
+    return [np.arange(a, b, dtype=np.int64) for a, b in zip(edges, edges[1:])]
+
+
+_TILE_CASES = {
+    "contiguous": lambda: (_contiguous_uids(_LEAF_COLS), False),
+    "far-from-zero": lambda: (_contiguous_uids(_LEAF_COLS, 2**30 + 5), False),
+    "placement": lambda: (_placement_uids(_LEAF_COLS), False),
+    "extra-columns": lambda: (_placement_uids(_LEAF_COLS), True),
+    "empty": lambda: ([], False),
+}
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES))
+def test_tile_deploy_stats_match_numpy_reference(case):
+    """Through the fetch and `present_tiles`, the dense-range device
+    reduction gives the tile ids and every per-tile map, bit for bit, of
+    a numpy reference that sorts the tile ids and sums each column into
+    its tile in column order; the registry folds the same values."""
+    rng = np.random.default_rng(11)
+    leaf_uids, with_extra = _TILE_CASES[case]()
+    names = [f"leaf{i}" for i in range(len(leaf_uids))]
+    stats_map = {n: _leaf_stats(rng, len(u)) for n, u in zip(names, leaf_uids)}
+    uids_map = dict(zip(names, leaf_uids))
+    extra = None
+    if with_extra:
+        extra = {"remapped_columns": {
+            n: jnp.asarray(rng.integers(0, 2, len(u)).astype(np.float32))
+            for n, u in zip(names, leaf_uids)
+        }}
+    tile_ids, maps = present_tiles(
+        metrics.fetch(tile_deploy_stats(stats_map, uids_map, _CPT, extra))
+    )
+
+    if not names:
+        assert tile_ids.shape == (0,) and maps == {}
+        return
+    uids = np.concatenate([np.asarray(u, np.int64) for u in leaf_uids])
+    ref_ids, inv = np.unique(uids // _CPT, return_inverse=True)
+
+    def cat(vecs):
+        return np.concatenate([np.asarray(v) for v in vecs]).astype(np.float32)
+
+    cols = {
+        "gave_up_cells": cat(s.gave_up for s in stats_map.values()),
+        "retry_pulses": cat(s.retry_pulses for s in stats_map.values()),
+        "write_pulses": cat(s.write_pulses for s in stats_map.values()),
+        "verify_reads": cat(s.reads for s in stats_map.values()),
+        "err2_sum": cat(s.rms_error_lsb for s in stats_map.values()) ** 2,
+    }
+    if extra:
+        cols["remapped_columns"] = cat(extra["remapped_columns"][n] for n in names)
+    ref = {}
+    for metric, vals in cols.items():
+        ref[metric] = np.zeros(ref_ids.shape, np.float32)
+        np.add.at(ref[metric], inv, vals)
+    ref["columns"] = np.bincount(inv, minlength=len(ref_ids)).astype(np.float64)
+
+    assert tile_ids.dtype == np.int64 and np.array_equal(tile_ids, ref_ids)
+    assert sorted(maps) == sorted(ref)
+    for metric, want in ref.items():
+        got = maps[metric]
+        assert got.dtype == want.dtype, metric
+        np.testing.assert_array_equal(got, want, err_msg=metric)
+        obs.health_registry.fold_tiles(f"t.{metric}", tile_ids, got)
+        assert obs.health_registry.tiles(f"t.{metric}") == {
+            int(t): float(v) for t, v in zip(ref_ids, want)
+        }
+
+
+def test_tile_deploy_stats_refuses_uids_past_int32():
+    """The uids go to the device as int32: one past its range is refused,
+    not wrapped onto another tile."""
+    rng = np.random.default_rng(0)
+    uids = {"leaf": np.array([2**31 - 2, 2**31 - 1, 2**31], np.int64)}
+    with pytest.raises(ValueError, match="int32"):
+        tile_deploy_stats({"leaf": _leaf_stats(rng, 3)}, uids, _CPT)
+
+
+def _deploy_span():
+    return [e for e in obs.trace.events()
+            if e["name"] == "deploy" and e["ph"] == "X"][-1]
+
+
 def test_deploy_health_rides_single_sync():
     """Tile health maps + deploy digests populate on the batched
     deploy's ONE host sync — faulty silicon shows up as per-tile
-    give-up mass without any extra fetch."""
+    give-up mass without any extra fetch.  A contiguous deploy fills
+    its dense tile range: the `deploy` span's two tile counters agree."""
     fc = FaultConfig(p_stuck_hrs=0.05, columns_per_tile=16, tiles_per_chip=4)
     pipeline.reset_counters()
     deploy_arrays(jax.random.PRNGKey(3), _tiny_params(), _WV, fault_cfg=fc)
@@ -112,6 +225,40 @@ def test_deploy_health_rides_single_sync():
                  "deploy.iterations_per_column"):
         d = obs.digests.get(name)
         assert d is not None and d.count > 0
+    args = _deploy_span()["args"]
+    n_tiles = len(obs.health_registry.tiles("deploy.columns"))
+    assert args["health_tile_range"] == args["health_tiles"] == n_tiles
+    assert metrics.value("deploy.health_tiles") == n_tiles
+    assert metrics.value("deploy.health_tile_range") == n_tiles
+
+
+@pytest.mark.parametrize("placement", [False, True], ids=["packed", "placement"])
+def test_deploy_remap_health_rides_single_sync(placement):
+    """The two-pass spare-remap deploy still makes ONE host sync, with
+    the per-tile remapped-column map riding it.  Placement spreads the
+    uids over its provision of tiles, so the dense range it reduces
+    holds empty tiles that the fold leaves out (the per-tile fault-rate
+    spread gives it tiles to prefer)."""
+    fc = FaultConfig(p_stuck_hrs=0.05, columns_per_tile=16, tiles_per_chip=4,
+                     sigma_tile_fault_dec=1.0)
+    rc = remap.RemapConfig(spare_frac=0.25, placement=placement)
+    pipeline.reset_counters()
+    dep, rep = deploy_arrays(
+        jax.random.PRNGKey(3), _tiny_params(), _WV, fault_cfg=fc, remap_cfg=rc,
+    )
+    assert pipeline.host_sync_count() == 1
+    remapped = obs.health_registry.tiles("deploy.remapped_columns")
+    assert sum(remapped.values()) == rep.remapped_columns
+    uids = np.concatenate([a.uids for a in dep.arrays.values()])
+    present = np.unique(uids // 16)
+    assert sorted(obs.health_registry.tiles("deploy.columns")) == list(present)
+    args = _deploy_span()["args"]
+    assert args["health_tiles"] == len(present)
+    assert args["health_tile_range"] == present[-1] - present[0] + 1
+    if placement:
+        assert args["health_tiles"] < args["health_tile_range"]
+    else:
+        assert args["health_tiles"] == args["health_tile_range"]
 
 
 # ------------------------------------- injected degradation -> SLO epoch
